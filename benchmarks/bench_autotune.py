@@ -151,6 +151,8 @@ def run(scale: float = SCALE, strict: bool = False,
 
 
 if __name__ == "__main__":
+    from repro.launch.cache import enable_compile_cache
+    enable_compile_cache()
     import argparse
 
     from .common import emit

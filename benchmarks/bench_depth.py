@@ -88,5 +88,7 @@ def run(scale: float = SCALE, max_depth: int = 8, per_depth: int = 24
 
 
 if __name__ == "__main__":
+    from repro.launch.cache import enable_compile_cache
+    enable_compile_cache()
     from .common import emit
     emit(run())

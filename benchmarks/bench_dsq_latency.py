@@ -47,5 +47,7 @@ def run(scale: float = SCALE) -> List[Dict]:
 
 
 if __name__ == "__main__":
+    from repro.launch.cache import enable_compile_cache
+    enable_compile_cache()
     from .common import emit
     emit(run())

@@ -71,5 +71,7 @@ def run() -> List[Dict]:
 
 
 if __name__ == "__main__":
+    from repro.launch.cache import enable_compile_cache
+    enable_compile_cache()
     from .common import emit
     emit(run())

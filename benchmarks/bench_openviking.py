@@ -73,5 +73,7 @@ def run(n_queries: int = 64, k: int = 5) -> List[Dict]:
 
 
 if __name__ == "__main__":
+    from repro.launch.cache import enable_compile_cache
+    enable_compile_cache()
     from .common import emit
     emit(run())

@@ -107,5 +107,7 @@ def markdown_table(mesh: str = "16x16", tag: Optional[str] = None) -> str:
 
 
 if __name__ == "__main__":
+    from repro.launch.cache import enable_compile_cache
+    enable_compile_cache()
     from .common import emit
     emit(run())

@@ -1,68 +1,54 @@
-"""Sharded vs single-device ``dsq_batch`` on a forced 8-host-device mesh.
+"""Sharded vs single-device ``dsq_batch`` over the devices this process sees.
 
-The inner measurement runs in a subprocess with
-``XLA_FLAGS=--xla_force_host_platform_device_count=8`` (the device count is
-locked at first jax init, so the harness process cannot force it itself).
-Eight simulated host devices share one CPU, so wall-clock speedup is
-*reported, never gated* — what this benchmark measures and (``--smoke``)
-enforces is the serving-tier contract:
+Runs in one process: the mesh spans ``jax.devices()`` (one process per chip —
+no child process is started, so nothing competes for an accelerator this
+process already holds). On a CPU host, emulate a mesh by starting the process
+with ``XLA_FLAGS=--xla_force_host_platform_device_count=8``; emulated devices
+share one CPU, so wall-clock speedup is *reported, never gated*. With fewer
+than two devices it fails (a one-shard mesh merges nothing). What this
+benchmark measures and (``--smoke``) enforces is the serving-tier contract:
 
-* bit-identical (scores, ids) to the single-device flat batch path, before
-  AND immediately after a ``dsm_batch`` of move/merge ops;
+* the same (ids, scores) as the single-device flat batch path — scores within
+  a few fp32 ULP (``vectordb.parity``) — before AND immediately after a
+  ``dsm_batch`` of move/merge ops;
 * per-shard accounting: mask upload happens once (token-validated slots),
   repeated batches hit resident slots, DSM deltas *patch* the shard-resident
   words (patched bytes strictly below one full re-upload of the surviving
   scopes), and the collective term stays O(shards * B * k);
 * incremental ingest growth scatters only the new rows (no re-shard).
 
-    PYTHONPATH=src python -m benchmarks.bench_sharded [--scale S] \\
-        [--smoke] [--json out.json]
+    [XLA_FLAGS=--xla_force_host_platform_device_count=8] PYTHONPATH=src \
+        python -m benchmarks.bench_sharded [--scale S] [--smoke] [--json F]
 """
 from __future__ import annotations
 
 import json
-import os
-import subprocess
-import sys
-from pathlib import Path
 from typing import Dict, List
 
 SCALE = 0.01
-MARK = "BENCH_SHARDED_ROWS_JSON:"
 
 
 def run(scale: float = SCALE, smoke: bool = False) -> List[Dict]:
-    """Spawn the 8-device inner run and collect its rows (the harness
-    process keeps its 1-device jax state untouched)."""
-    env = dict(os.environ)
-    env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
-    src = str(Path(__file__).resolve().parents[1] / "src")
-    env["PYTHONPATH"] = src + (os.pathsep + env["PYTHONPATH"]
-                               if env.get("PYTHONPATH") else "")
-    cmd = [sys.executable, "-m", "benchmarks.bench_sharded", "--inner",
-           "--scale", str(scale)] + (["--smoke"] if smoke else [])
-    out = subprocess.run(cmd, capture_output=True, text=True, env=env,
-                         cwd=str(Path(__file__).resolve().parents[1]),
-                         timeout=1800)
-    if out.returncode != 0:
-        raise RuntimeError(f"bench_sharded inner failed:\n{out.stderr[-3000:]}")
-    for line in out.stdout.splitlines():
-        if line.startswith(MARK):
-            return json.loads(line[len(MARK):])
-    raise RuntimeError(f"no rows emitted:\n{out.stdout[-2000:]}")
-
-
-def _inner(scale: float, smoke: bool) -> List[Dict]:
     import time
 
     import jax
     import numpy as np
 
     from repro.vectordb import DirectoryVectorDB
+    from repro.vectordb.parity import topk_mismatch
 
     from .common import DIM, datasets
 
-    assert len(jax.devices()) == 8, jax.devices()
+    dev = jax.devices()[0]
+    if len(jax.devices()) < 2:
+        # a one-shard mesh has no cross-shard merge: every gate below would
+        # pass without testing what this benchmark is for
+        raise RuntimeError(
+            f"bench_sharded needs >= 2 devices, this process sees "
+            f"{len(jax.devices())} {dev.platform} device(s); on a CPU host "
+            f"start it with "
+            f"XLA_FLAGS=--xla_force_host_platform_device_count=8")
+    where = f"platform={dev.platform};devices={len(jax.devices())}"
     B, K, REPEAT = 64, 10, 3
     rng = np.random.default_rng(0)
     rows: List[Dict] = []
@@ -97,11 +83,13 @@ def _inner(scale: float, smoke: bool) -> List[Dict]:
             return db.dsq_batch(queries, paths, k=K, recursive=rec,
                                 executor="sharded")
 
-        # correctness gate: bit-identical to the single-device flat batch
-        rf, rs = flat_batch(), sharded_batch()
-        for a, b in zip(rf, rs):
-            assert np.array_equal(a.scores, b.scores)
-            assert np.array_equal(a.ids, b.ids)
+        def assert_parity():
+            for a, b in zip(flat_batch(), sharded_batch()):
+                err = topk_mismatch(b.scores, b.ids, a.scores, a.ids)
+                assert err is None, err
+
+        # correctness gate: the single-device flat batch's answer
+        assert_parity()
 
         def clock(fn):
             fn()
@@ -117,11 +105,11 @@ def _inner(scale: float, smoke: bool) -> List[Dict]:
             "steady-state batches must serve every scan scope from slots"
         rows.append({"name": f"sharded/{ds_name}/flat_batch",
                      "us_per_call": flat_us,
-                     "derived": f"B={B};k={K};devices=1"})
+                     "derived": f"B={B};k={K};{where}"})
         rows.append({
             "name": f"sharded/{ds_name}/sharded_batch",
             "us_per_call": shard_us,
-            "derived": (f"speedup={flat_us / shard_us:.2f}x(emulated);"
+            "derived": (f"speedup={flat_us / shard_us:.2f}x;{where};"
                         f"n_shards={acct.n_shards};"
                         f"launches={acct.launches};"
                         f"collective_bytes={acct.collective_bytes};"
@@ -131,10 +119,7 @@ def _inner(scale: float, smoke: bool) -> List[Dict]:
         m0, up0 = ex.mask_bytes_patched, ex.mask_bytes_uploaded
         db.dsm_batch([("mkdir", "/bench_stage/"),
                       ("move", "/bench_src/fresh/", "/bench_stage/")])
-        rf, rs = flat_batch(), sharded_batch()
-        for a, b in zip(rf, rs):
-            assert np.array_equal(a.scores, b.scores)
-            assert np.array_equal(a.ids, b.ids)
+        assert_parity()
         patched_bytes = ex.mask_bytes_patched - m0
         reupload_bytes = ex.mask_bytes_uploaded - up0
         full_row = ex.view.n_words * 4
@@ -178,14 +163,7 @@ def main() -> None:
                     help="enforce the correctness/accounting acceptance gate")
     ap.add_argument("--json", default="",
                     help="also write the result rows to this JSON file")
-    ap.add_argument("--inner", action="store_true",
-                    help="(internal) run the measurement in this process; "
-                         "requires the 8-device XLA_FLAGS already set")
     args = ap.parse_args()
-    if args.inner:
-        rows = _inner(args.scale, args.smoke)
-        print(MARK + json.dumps(rows))
-        return
     rows = run(scale=args.scale, smoke=args.smoke)
     from .common import emit
     emit(rows)
@@ -195,4 +173,6 @@ def main() -> None:
 
 
 if __name__ == "__main__":
+    from repro.launch.cache import enable_compile_cache
+    enable_compile_cache()
     main()

@@ -40,7 +40,7 @@ def main() -> None:
          lambda: bench_dsq_batch.run(args.scale)),
         ("ivf_batch", "Batched device-resident IVF DSQ vs per-request loop",
          lambda: bench_ivf_batch.run(args.scale)),
-        ("sharded", "Sharded vs single-device dsq_batch (8-device host mesh)",
+        ("sharded", "Sharded vs single-device dsq_batch (visible devices)",
          lambda: bench_sharded.run(args.scale)),
         ("quantized", "Int8 scalar-quantized tier vs fp32 exact scan",
          lambda: bench_quantized.run(args.scale)),
@@ -89,4 +89,6 @@ def main() -> None:
 
 
 if __name__ == "__main__":
+    from repro.launch.cache import enable_compile_cache
+    enable_compile_cache()
     main()

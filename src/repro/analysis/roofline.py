@@ -1,6 +1,7 @@
 """Roofline accounting from compiled dry-run artifacts.
 
-Three terms per (arch × shape × mesh) cell, TPU v5e constants:
+Three terms per (arch × shape × mesh) cell, TPU v5e peaks (the dry-run's
+target chip; :data:`DEVICE_PEAKS` keeps every chip's published peaks):
 
     compute    = HLO_FLOPs      / (chips × 197e12 FLOP/s bf16)
     memory     = HLO_bytes      / (chips × 819e9  B/s HBM)
@@ -24,8 +25,39 @@ import re
 from dataclasses import dataclass
 from typing import Dict, Optional
 
-PEAK_FLOPS = 197e12        # bf16 per chip (TPU v5e)
-HBM_BW = 819e9             # bytes/s per chip
+
+@dataclass(frozen=True)
+class DevicePeaks:
+    """Published per-chip peaks of one accelerator kind."""
+    bf16_flops: float          # FLOP/s
+    int8_ops: float            # OP/s
+    hbm_bytes_per_s: float     # HBM bandwidth
+    hbm_bytes: float           # HBM capacity
+    source: str
+
+
+_V5E = DevicePeaks(bf16_flops=197e12, int8_ops=393e12, hbm_bytes_per_s=819e9,
+                   hbm_bytes=16e9,
+                   source="Google Cloud documentation, 'TPU v5e'")
+
+# keyed by ``jax.Device.device_kind``; JAX names a v5e "TPU v5 lite"
+DEVICE_PEAKS: Dict[str, DevicePeaks] = {"TPU v5 lite": _V5E}
+
+
+def device_peaks(device_kind: str) -> DevicePeaks:
+    """Peaks of ``device_kind``; an unknown kind is an error, never a
+    stand-in chip's numbers."""
+    try:
+        return DEVICE_PEAKS[device_kind]
+    except KeyError:
+        raise ValueError(
+            f"no published peaks for device kind {device_kind!r} "
+            f"(known: {sorted(DEVICE_PEAKS)}); add it to DEVICE_PEAKS "
+            f"with its source") from None
+
+
+PEAK_FLOPS = _V5E.bf16_flops   # the dry-run's target chip
+HBM_BW = _V5E.hbm_bytes_per_s
 LINK_BW = 50e9             # bytes/s per ICI link
 
 _DTYPE_BYTES = {
@@ -77,8 +109,7 @@ def parse_collectives(hlo_text: str) -> Dict[str, float]:
 
 
 def cost_summary(compiled) -> Dict[str, float]:
-    from ..compat import cost_analysis_dict
-    cost = cost_analysis_dict(compiled)
+    cost = compiled.cost_analysis() or {}
     out = {
         "flops": float(cost.get("flops", 0.0)),
         "bytes": float(cost.get("bytes accessed", 0.0)),

@@ -113,7 +113,8 @@ class PEOfflineIndex(ScopeIndex):
         total = self.postings.get(path)
         if total is None:
             return RoaringBitmap()
-        child_names = self.aux.children(path)
+        # a snapshot: DSM workers edit the live child set concurrently
+        child_names = tuple(self.aux.children(path))
         t1 = time.perf_counter_ns()
         children = RoaringBitmap()
         fetches = 1

@@ -30,7 +30,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from .. import compat
 
 
 def _merge_local_topk(v, i, axes, n_dev: int, n_loc: int, k: int):
@@ -77,7 +76,7 @@ def make_scoped_search(mesh: Mesh, n_total: int, dim: int, k: int,
         v, i = jax.lax.top_k(scores, k)                      # (q, k) local
         return _merge_local_topk(v, i, axes, n_dev, n_loc, k)
 
-    fn = compat.shard_map(
+    fn = jax.shard_map(
         local_search, mesh=mesh,
         in_specs=(P(axes, None), P(axes), P(None, None)),
         out_specs=(P(None, None), P(None, None)),
@@ -124,7 +123,7 @@ def make_multi_scope_search(mesh: Mesh, n_total: int, dim: int, k: int,
         v, i = jax.lax.top_k(scores, k)
         return _merge_local_topk(v, i, axes, n_dev, n_loc, k)
 
-    fn = compat.shard_map(
+    fn = jax.shard_map(
         local_search, mesh=mesh,
         in_specs=(P(axes, None), P(None, axes), P(None), P(None, None)),
         out_specs=(P(None, None), P(None, None)),
@@ -151,12 +150,13 @@ def make_sharded_batch_search(mesh: Mesh, n_total: int, dim: int, k: int,
     Differences from :func:`make_multi_scope_search`: the mask matrix is a
     persistent *table* (slots owned by ``ShardedExecutor``, patched in place
     by DSM deltas) rather than a per-batch stack, the tombstone mask is ANDed
-    in-register, and the ip/cos scoring expression is kept textually
-    identical to the single-device flat scan twin (``flat._multi_scan_topk``)
-    so the merged (scores, ids) are bit-identical to the flat batch path on
-    CPU. (The l2 norm term is computed in-kernel here, while the flat twin
-    reads the store's cached device norms — same values through np/jnp fp32
-    sums in practice, but l2 is outside the bit-identity contract.)"""
+    in-register, and the ip/cos scoring is the flat scan's full-precision
+    fp32 product. The merged (scores, ids) match the flat batch path up to
+    the rounding of the shard-local matmul shape: the same ids (k-boundary
+    ties aside) with scores a few fp32 ULP apart — XLA rounds a dot
+    differently per operand shape, so bit-identity cannot hold across
+    backends. (The l2 norm term is computed in-kernel here, while the flat
+    twin reads the store's cached device norms.)"""
     axes = tuple(mesh.axis_names)
     n_dev = int(np.prod([mesh.shape[a] for a in axes]))
     assert n_total % n_dev == 0, (n_total, n_dev)
@@ -165,8 +165,8 @@ def make_sharded_batch_search(mesh: Mesh, n_total: int, dim: int, k: int,
     assert 0 < k <= n_loc, (k, n_loc, "per-shard top-k must fit local rows")
 
     def local_search(db_l, words_l, alive_l, sids, q):
-        # identical expression to flat._multi_scan_topk (bit-identity)
-        scores = q @ db_l.T
+        # the flat scan's exact fp32 scoring (``flat.exact_scores``)
+        scores = jnp.matmul(q, db_l.T, precision=jax.lax.Precision.HIGHEST)
         if metric == "l2":
             scores = 2.0 * scores - jnp.sum(db_l * db_l, axis=-1)[None, :]
         from ..kernels.ref import unpack_words_ref
@@ -176,7 +176,7 @@ def make_sharded_batch_search(mesh: Mesh, n_total: int, dim: int, k: int,
         v, i = jax.lax.top_k(scores, k)
         return _merge_local_topk(v, i, axes, n_dev, n_loc, k)
 
-    fn = compat.shard_map(
+    fn = jax.shard_map(
         local_search, mesh=mesh,
         in_specs=(P(axes, None), P(None, axes), P(axes), P(None),
                   P(None, None)),
@@ -232,7 +232,7 @@ def make_sharded_batch_search_i8(mesh: Mesh, n_total: int, dim: int, r: int,
         v, i = jax.lax.top_k(scores, r)
         return _merge_local_topk(v, i, axes, n_dev, n_loc, r)
 
-    fn = compat.shard_map(
+    fn = jax.shard_map(
         local_search, mesh=mesh,
         in_specs=(P(axes, None), P(axes), P(None, axes), P(axes), P(None),
                   P(None, None), P(None)),
@@ -284,7 +284,7 @@ def make_sharded_batch_search_pq(mesh: Mesh, n_total: int, m: int, r: int):
         v, i = jax.lax.top_k(scores, r)
         return _merge_local_topk(v, i, axes, n_dev, n_loc, r)
 
-    fn = compat.shard_map(
+    fn = jax.shard_map(
         local_search, mesh=mesh,
         in_specs=(P(axes, None), P(None, axes), P(axes), P(None),
                   P(None, None, None)),

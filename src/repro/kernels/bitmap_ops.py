@@ -26,7 +26,8 @@ def _kernel(a_ref, b_ref, words_ref, count_ref, acc_ref):
     w = a_ref[...] & b_ref[...]
     words_ref[...] = w
     pc = jax.lax.population_count(w)
-    acc_ref[0, 0] += jnp.sum(pc.astype(jnp.int32))
+    acc_ref[...] += jnp.full((1, 1), jnp.sum(pc.astype(jnp.int32)),
+                             jnp.int32)
 
     @pl.when(i == pl.num_programs(0) - 1)
     def _flush():

@@ -58,30 +58,40 @@ def multi_scope_topk_ref(queries: jax.Array, rows: jax.Array,
     return vals, ids.astype(jnp.int32)
 
 
-def ivf_gather_topk_ref(queries: np.ndarray, cand_rows: np.ndarray,
-                        cand_ids: np.ndarray, qwords: np.ndarray,
+def ivf_gather_topk_ref(queries: np.ndarray, probe: np.ndarray,
+                        list_start: np.ndarray, list_nblk: np.ndarray,
+                        rows: np.ndarray, ids: np.ndarray, qwords: np.ndarray,
                         k: int = 10, metric: str = "ip"
                         ) -> Tuple[np.ndarray, np.ndarray]:
-    """Unfused numpy oracle for the batched-IVF gather→score→top-k launch:
-    materializes every (b, c) score, expands each query's packed scope words,
-    full stable sort. cand_ids -1 marks CSR padding slots."""
+    """Unfused numpy oracle for the batched-IVF list scan: per query,
+    concatenate the probed lists' blocks of the list-major store in probe
+    order, score every slot, expand the query's packed scope words, full
+    stable sort. ``ids`` -1 marks list padding."""
     q = np.asarray(queries, dtype=np.float32)
-    x = np.asarray(cand_rows, dtype=np.float32)
-    cand = np.asarray(cand_ids, dtype=np.int64)
+    x = np.asarray(rows, dtype=np.float32)
+    table = np.asarray(ids, dtype=np.int64)
+    block = table.shape[1]
     words = np.asarray(qwords, dtype=np.uint32)
-    scores = np.einsum("bcd,bd->bc", x, q)
-    if metric == "l2":
-        scores = 2.0 * scores - np.einsum("bcd,bcd->bc", x, x)
-    safe = np.maximum(cand, 0)
-    rows_idx = np.arange(q.shape[0])[:, None]
-    bits = (words[rows_idx, safe >> 5] >> (safe & 31).astype(np.uint32)) & 1
-    mask = (cand >= 0) & (bits != 0)
-    scores = np.where(mask, scores.astype(np.float32), NEG_INF)
-    order = np.argsort(-scores, axis=1, kind="stable")[:, :k]
-    vals = np.take_along_axis(scores, order, axis=1)
-    ids = np.take_along_axis(cand, order, axis=1)
-    ids = np.where(vals <= NEG_INF, -1, ids)
-    return vals, ids.astype(np.int32)
+    out_v = np.full((len(q), k), NEG_INF, np.float32)
+    out_i = np.full((len(q), k), -1, np.int32)
+    for b in range(len(q)):
+        blks = np.concatenate([
+            np.arange(list_start[c], list_start[c] + list_nblk[c])
+            for c in np.asarray(probe)[b]])
+        cand = table[blks].reshape(-1)
+        xr = x.reshape(-1, block, x.shape[1])[blks].reshape(-1, x.shape[1])
+        scores = xr @ q[b]
+        if metric == "l2":
+            scores = 2.0 * scores - np.sum(xr * xr, axis=1)
+        safe = np.maximum(cand, 0)
+        bits = (words[b, safe >> 5] >> (safe & 31).astype(np.uint32)) & 1
+        scores = np.where((cand >= 0) & (bits != 0),
+                          scores.astype(np.float32), NEG_INF)
+        order = np.argsort(-scores, kind="stable")[:k]
+        kk = len(order)
+        out_v[b, :kk] = scores[order]
+        out_i[b, :kk] = np.where(scores[order] <= NEG_INF, -1, cand[order])
+    return out_v, out_i
 
 
 def _unpack_words_np(words: np.ndarray, n: int) -> np.ndarray:

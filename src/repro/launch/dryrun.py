@@ -50,8 +50,7 @@ def compile_cell(arch: str, shape_name: str, multi_pod: bool,
     metrics["flops_global"] = metrics["flops"] * chips
     metrics["bytes_global"] = metrics["bytes"] * chips
     print(compiled.memory_analysis())
-    from ..compat import cost_analysis_dict
-    cost = cost_analysis_dict(compiled)
+    cost = compiled.cost_analysis() or {}
     print({k: cost[k] for k in ("flops", "bytes accessed") if k in cost})
     del compiled, lowered
     return metrics
@@ -239,4 +238,6 @@ def main():
 
 
 if __name__ == "__main__":
+    from repro.launch.cache import enable_compile_cache
+    enable_compile_cache()
     main()

@@ -10,9 +10,15 @@ from typing import Optional, Tuple
 
 import jax
 import numpy as np
-from jax.sharding import Mesh
+from jax.sharding import AxisType, Mesh
 
-from ..compat import make_mesh
+
+def make_mesh(axis_shapes: Tuple[int, ...], axis_names: Tuple[str, ...],
+              devices=None) -> Mesh:
+    """``jax.make_mesh`` with every axis of the Auto type."""
+    return jax.make_mesh(axis_shapes, axis_names,
+                         axis_types=(AxisType.Auto,) * len(axis_names),
+                         devices=devices)
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> Mesh:

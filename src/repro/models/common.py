@@ -236,8 +236,9 @@ def spec_tree(shape_tree, logical_tree, mesh: Mesh, rules=None):
 def _manual_axes() -> set:
     """Axes that are Manual in the current trace context (inside shard_map):
     sharding constraints must not mention them."""
-    from ..compat import manual_axis_names
-    return manual_axis_names()
+    am = jax.sharding.get_abstract_mesh()
+    return {n for n, t in zip(am.axis_names, am.axis_types)
+            if t == jax.sharding.AxisType.Manual}
 
 
 def constrain(x, logical: Sequence[Optional[str]], mesh: Optional[Mesh],

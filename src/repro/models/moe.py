@@ -27,7 +27,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, PartitionSpec as P
 
-from .. import compat
 from .layers import Spec
 
 
@@ -142,7 +141,7 @@ def moe_apply(p: Dict[str, jax.Array], x: jax.Array, cfg,
                                   act=act)
             return jax.lax.psum(y, "model")
 
-        y = compat.shard_map(
+        y = jax.shard_map(
             shard_fn, mesh=mesh,
             in_specs=(P(data_axes, None), P(None, None),
                       P("model", None, None), P("model", None, None),

@@ -16,7 +16,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-from .. import compat
 from ..models import loss_fn
 from ..models.common import ArchConfig
 from .optimizer import OptConfig, adamw_update
@@ -33,7 +32,7 @@ def int8_psum(tree, axis: str):
         q = jnp.clip(jnp.round(g32 / scale), -127, 127).astype(jnp.int8)
         summed = jax.lax.psum(q.astype(jnp.int32), axis)
         return (summed.astype(jnp.float32) * scale
-                / compat.axis_size(axis)).astype(g.dtype)
+                / jax.lax.axis_size(axis)).astype(g.dtype)
     return jax.tree.map(one, tree)
 
 
@@ -98,7 +97,7 @@ def make_train_step(cfg: ArchConfig, opt_cfg: OptConfig,
             manual = frozenset({"pod"})
         else:
             manual = frozenset(mesh.axis_names)
-        return compat.shard_map(
+        return jax.shard_map(
             inner, mesh=mesh,
             in_specs=(P(), P(), P("pod")),
             out_specs=(P(), P(), P()),

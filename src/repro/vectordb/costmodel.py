@@ -13,9 +13,11 @@ answers each question from one of three sources, in strength order:
   rescore factor, an nprobe recall/latency curve, the fastest kernel block
   shapes, and the batch-size service curve.
 * ``"roofline"`` — the analytic fallback when an artifact exists but was
-  calibrated on a *different* backend string: bandwidth terms from
-  :mod:`repro.analysis.roofline` constants (a measured artifact never
-  transfers across backends — the whole point of calibrating).
+  calibrated on a *different* backend string: bandwidth terms from the
+  running device's published peaks (:func:`repro.analysis.roofline
+  .device_peaks`, keyed by ``device_kind``; an unknown device is an error).
+  A measured artifact never transfers across backends — the whole point of
+  calibrating.
 * ``"heuristic"`` — the hand-set constants, bit-for-bit: this is the default
   when no artifact is supplied, and the contract is that a heuristic model
   reproduces the pre-cost-model planner EXACTLY (gather threshold 0.05,
@@ -42,7 +44,7 @@ import json
 import os
 from typing import Dict, List, Optional, Sequence, Tuple
 
-from ..analysis.roofline import HBM_BW
+from ..analysis.roofline import DevicePeaks, device_peaks
 from .quant import DEFAULT_RESCORE_FACTOR, resolve_rescore_k
 
 SCHEMA_VERSION = 1
@@ -62,8 +64,10 @@ NPROBE_FLOOR = 8                 # the hand-set default; measured never probes l
 # access penalty of a gathered row fetch vs the streaming scan read
 LAUNCH_NS = 50_000.0
 GATHER_PENALTY = 8.0
+# the heuristic model's hand-set streaming bandwidth (bytes/s), used only to
+# price maintenance work; the roofline source reads the device's own peak
+HEURISTIC_HBM_BW = 819e9
 
-_KERNEL_DEFAULT_BLOCKS = {"block_q": 8, "block_n": 1024}
 TUNABLE_KERNELS = ("scoped_topk", "scoped_topk_i8", "scoped_topk_pq",
                    "multi_scope_topk", "multi_scope_topk_i8",
                    "multi_scope_topk_pq")
@@ -72,6 +76,11 @@ TUNABLE_KERNELS = ("scoped_topk", "scoped_topk_i8", "scoped_topk_pq",
 def _current_backend() -> str:
     import jax
     return jax.default_backend()
+
+
+def _current_device_kind() -> str:
+    import jax
+    return jax.devices()[0].device_kind
 
 
 class CalibrationArtifact:
@@ -148,10 +157,18 @@ class CostModel:
     ``"heuristic"`` (no artifact — the hand-set constants, exactly)."""
 
     def __init__(self, source: str,
-                 artifact: Optional[CalibrationArtifact] = None):
+                 artifact: Optional[CalibrationArtifact] = None,
+                 peaks: Optional[DevicePeaks] = None):
         assert source in ("heuristic", "roofline", "measured"), source
+        assert (source == "roofline") == (peaks is not None), source
         self.source = source
         self.artifact = artifact
+        self.peaks = peaks
+
+    @property
+    def hbm_bw(self) -> float:
+        return (self.peaks.hbm_bytes_per_s if self.peaks is not None
+                else HEURISTIC_HBM_BW)
 
     def __repr__(self) -> str:
         backend = self.artifact.backend if self.artifact else None
@@ -163,13 +180,18 @@ class CostModel:
 
     @classmethod
     def from_artifact(cls, artifact: CalibrationArtifact,
-                      backend: Optional[str] = None) -> "CostModel":
+                      backend: Optional[str] = None,
+                      device_kind: Optional[str] = None) -> "CostModel":
         """Measured when the artifact's backend matches the running one,
         roofline fallback otherwise — measurements never transfer across
-        backends."""
+        backends. The roofline source prices with ``device_kind``'s
+        published peaks (default: the running device); a kind without
+        published peaks raises ``ValueError``."""
         backend = _current_backend() if backend is None else backend
         if artifact.backend != backend:
-            return cls("roofline", artifact)
+            kind = _current_device_kind() if device_kind is None \
+                else device_kind
+            return cls("roofline", artifact, peaks=device_peaks(kind))
         return cls("measured", artifact)
 
     # ------------------------------------------------------------ cost terms
@@ -189,7 +211,7 @@ class CostModel:
             t = self.artifact.terms["scan_ns"].get(precision)
             if t is not None:
                 return float(t["a"]) + float(t["per_byte"]) * nbytes
-        return LAUNCH_NS + nbytes / HBM_BW * 1e9
+        return LAUNCH_NS + nbytes / self.hbm_bw * 1e9
 
     def gather_ns(self, m: int, dim: int = 64) -> float:
         """Predicted ns of one fp32 gather-plan launch over ``m`` rows."""
@@ -198,7 +220,7 @@ class CostModel:
             if t is not None:
                 return float(t["a"]) + float(t["per_row"]) * m
         return LAUNCH_NS + m * self.row_bytes("fp32", dim) \
-            * GATHER_PENALTY / HBM_BW * 1e9
+            * GATHER_PENALTY / self.hbm_bw * 1e9
 
     def rescore_ns(self, r: int, dim: int = 64) -> float:
         """Predicted ns of one exact fp32 gather-rescore over ``r`` rows."""
@@ -207,7 +229,7 @@ class CostModel:
             if t is not None:
                 return float(t["a"]) + float(t["per_row"]) * r
         return LAUNCH_NS + r * self.row_bytes("fp32", dim) \
-            * GATHER_PENALTY / HBM_BW * 1e9
+            * GATHER_PENALTY / self.hbm_bw * 1e9
 
     # ------------------------------------------------------------- decisions
     def gather_threshold(self, n: Optional[int] = None,
@@ -242,18 +264,21 @@ class CostModel:
         exact = self.scan_ns(n, "fp32", dim)
         return "fp32" if exact <= quantized else requested
 
-    def pick_rescore_k(self, k: int, rescore_k: Optional[int],
-                       n: int) -> Optional[int]:
+    def pick_rescore_k(self, k: int, rescore_k: Optional[int], n: int,
+                       floor: int = DEFAULT_RESCORE_FACTOR) -> Optional[int]:
         """Effective ``rescore_k`` request value: an explicit caller value
-        always wins; measured models substitute their recall-gated factor,
-        floored at the hand-set ``DEFAULT_RESCORE_FACTOR`` so the window
-        never narrows below the pre-cost-model recall contract."""
-        if rescore_k is not None or self.source != "measured":
+        always wins; otherwise the precision's hand-set window ``floor * k``
+        (``None`` = the ``DEFAULT_RESCORE_FACTOR`` default; PQ passes its
+        wider ``quant.pq_rescore_factor``), which measured models may only
+        widen with their recall-gated factor — the window never narrows
+        below the pre-cost-model recall contract."""
+        if rescore_k is not None:
             return rescore_k
-        factor = self.artifact.terms.get("rescore_factor")
+        factor = (self.artifact.terms.get("rescore_factor")
+                  if self.source == "measured" else None)
         if factor is None:
-            return None
-        return max(int(factor), DEFAULT_RESCORE_FACTOR) * k
+            return None if floor == DEFAULT_RESCORE_FACTOR else floor * k
+        return max(int(factor), floor) * k
 
     def default_nprobe(self, n_lists: int) -> int:
         """IVF probe depth when the caller does not pass ``nprobe``; measured

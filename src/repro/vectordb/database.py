@@ -23,7 +23,8 @@ from .flat import FlatExecutor
 from .graph import PGIndex
 from .ivf import IVFIndex
 from .planner import BatchAccounting, BatchPlanner, ScopeMaskCache
-from .quant import resolve_rescore_k
+from .quant import (DEFAULT_RESCORE_FACTOR, pq_rescore_factor,
+                    resolve_rescore_k)
 from .sharded import ShardedExecutor
 from .store import VectorStore
 
@@ -161,6 +162,13 @@ class DirectoryVectorDB:
         self.store.mark_deleted(entry_id)
 
     # ------------------------------------------------------------------ DSQ
+    def _rescore_floor(self, precision: str) -> int:
+        """Hand-set rescore window per k of a two-phase precision: PQ's
+        widens with its subspace count (``quant.pq_rescore_factor``)."""
+        if precision == "pq":
+            return pq_rescore_factor(self.store.pq_m)
+        return DEFAULT_RESCORE_FACTOR
+
     def dsq(self, queries: np.ndarray, path: str, k: int = 10,
             recursive: bool = True, exclude: Sequence[str] = (),
             namespace: str = DEFAULT_NS, executor: str = "flat",
@@ -187,7 +195,8 @@ class DirectoryVectorDB:
         precision = model.pick_precision(
             precision, len(self.store), k, rescore_k,
             tiered=self.store.tiered_active(), dim=self.store.dim)
-        rescore_k = model.pick_rescore_k(k, rescore_k, len(self.store))
+        rescore_k = model.pick_rescore_k(k, rescore_k, len(self.store),
+                                         self._rescore_floor(precision))
         idx = self.namespaces[namespace]
         stats = ResolveStats()
         t0 = time.perf_counter_ns()
@@ -245,7 +254,8 @@ class DirectoryVectorDB:
         may differ with batch shape, like the fused-kernel caveat), ``pg``
         shares each unique scope's traversal mask (bit-identical), and
         ``sharded`` ranks every scan-plan request in one shard_map launch
-        over the row-sharded device mesh (bit-identical to ``flat``). The
+        over the row-sharded device mesh (same ids as ``flat``, scores
+        within a few fp32 ULP). The
         per-request fallback loop remains only for executor params the
         planner cannot plan.
 
@@ -272,7 +282,8 @@ class DirectoryVectorDB:
         precision = model.pick_precision(
             precision, len(self.store), k, rescore_k,
             tiered=self.store.tiered_active(), dim=self.store.dim)
-        rescore_k = model.pick_rescore_k(k, rescore_k, len(self.store))
+        rescore_k = model.pick_rescore_k(k, rescore_k, len(self.store),
+                                         self._rescore_floor(precision))
         queries = np.atleast_2d(np.asarray(queries, dtype=np.float32))
         B = queries.shape[0]
         if len(paths) != B:
@@ -479,8 +490,10 @@ class DirectoryVectorDB:
         executor's device-resident scope table (token-validated — repeated
         scopes and DSM-delta-patched scopes never re-upload) and ride ONE
         shard_map launch; selective gather-plan groups stay on the
-        single-device gather launch, exactly like the flat path. Results are
-        bit-identical to ``executor="flat"``. ``use_pallas`` only reaches
+        single-device gather launch, exactly like the flat path. Results
+        match ``executor="flat"``: the same ids, scores within a few fp32
+        ULP (the shard-local matmul shape rounds differently).
+        ``use_pallas`` only reaches
         the single-device flat twin (the small-store fallback) — the mesh
         launch has no fused-kernel variant."""
 

@@ -33,6 +33,32 @@ from .quant import int_exact_dot, quantize_rows, resolve_rescore_k
 from .store import VectorStore, pack_ids_to_words
 
 
+# Exact fp32 scoring: on the TPU an fp32 matmul at default precision is a
+# single bf16 pass, so every exact path asks for full fp32 explicitly.
+EXACT = jax.lax.Precision.HIGHEST
+
+
+def exact_scores(queries: jnp.ndarray, rows: jnp.ndarray) -> jnp.ndarray:
+    """(q, d) x (n, d) -> (q, n) fp32 inner products at full fp32 precision
+    — the scoring expression of every exact scan (flat and sharded)."""
+    return jnp.matmul(queries, rows.T, precision=EXACT)
+
+
+def bucket(n: int, floor: int = 8) -> int:
+    """Padded size of a variable launch axis (queries in a group, scopes in
+    a batch, gathered candidates): the next power of two >= ``max(n,
+    floor)``. Serving batches vary on every one of these axes, and each new
+    shape is a compile, so launches pad to a handful of sizes."""
+    return max(floor, 1 << max(n - 1, 0).bit_length())
+
+
+def pad_rows(x, n: int, value=0) -> np.ndarray:
+    """``x`` with its leading axis padded to ``n`` rows of ``value``."""
+    x = np.asarray(x)
+    pad = [(0, n - x.shape[0])] + [(0, 0)] * (x.ndim - 1)
+    return np.pad(x, pad, constant_values=value)
+
+
 def choose_plan(m: int, n: int, k: int,
                 threshold: float = GATHER_THRESHOLD) -> str:
     """THE gather/scan decision rule. ``FlatExecutor.search``, the
@@ -69,9 +95,9 @@ def _scan_topk(queries: jnp.ndarray, rows: jnp.ndarray, sq: jnp.ndarray,
     from ..kernels.ref import unpack_words_ref
     n = rows.shape[0]
     if metric in ("ip", "cos"):
-        scores = queries @ rows.T
+        scores = exact_scores(queries, rows)
     else:  # l2: argmax of -(||q||^2 - 2 q.x + ||x||^2) == argmax(2 q.x - ||x||^2)
-        scores = 2.0 * (queries @ rows.T) - sq[None, :]
+        scores = 2.0 * exact_scores(queries, rows) - sq[None, :]
     mask = unpack_words_ref(words, n)                       # (n,)
     scores = jnp.where(mask[None, :], scores, -jnp.inf)
     return jax.lax.top_k(scores, k)
@@ -92,9 +118,9 @@ def _multi_scan_topk(queries: jnp.ndarray, rows: jnp.ndarray,
     from ..kernels.ref import unpack_words_ref
     n = rows.shape[0]
     if metric in ("ip", "cos"):
-        scores = queries @ rows.T
+        scores = exact_scores(queries, rows)
     else:
-        scores = 2.0 * (queries @ rows.T) - sq[None, :]
+        scores = 2.0 * exact_scores(queries, rows) - sq[None, :]
     masks = unpack_words_ref(mask_words, n)                 # (n_scopes, n)
     valid = jnp.take(masks, scope_ids, axis=0)              # (B, n)
     scores = jnp.where(valid, scores, -jnp.inf)
@@ -149,14 +175,15 @@ def _multi_scan_topk_i8(q_i8: jnp.ndarray, q_scale: jnp.ndarray,
 @functools.partial(jax.jit, static_argnames=("k", "metric"))
 def _gather_topk_i8(q_i8: jnp.ndarray, q_scale: jnp.ndarray,
                     cand_i8: jnp.ndarray, cand_scale: jnp.ndarray,
-                    cand_sq: jnp.ndarray,
+                    cand_sq: jnp.ndarray, valid: jnp.ndarray,
                     k: int, metric: str) -> Tuple[jnp.ndarray, jnp.ndarray]:
-    """int8 phase of the gather plan: score only the |C| candidate codes."""
+    """int8 phase of the gather plan: score only the |C| candidate codes
+    (``valid`` False on padding lanes)."""
     scores = _int_exact_dot(q_i8, cand_i8) * (
         q_scale[:, None] * cand_scale[None, :])
     if metric == "l2":
         scores = 2.0 * scores - cand_sq[None, :]
-    return jax.lax.top_k(scores, k)
+    return jax.lax.top_k(jnp.where(valid[None, :], scores, -jnp.inf), k)
 
 
 def _adc_scores(lut: jnp.ndarray, codes: jnp.ndarray) -> jnp.ndarray:
@@ -204,9 +231,12 @@ def _multi_scan_topk_pq(lut: jnp.ndarray, codes: jnp.ndarray,
 
 @functools.partial(jax.jit, static_argnames=("k",))
 def _gather_topk_pq(lut: jnp.ndarray, cand_codes: jnp.ndarray,
+                    valid: jnp.ndarray,
                     k: int) -> Tuple[jnp.ndarray, jnp.ndarray]:
-    """ADC phase of the gather plan: score only the |C| candidate codes."""
-    return jax.lax.top_k(_adc_scores(lut, cand_codes), k)
+    """ADC phase of the gather plan: score only the |C| candidate codes
+    (``valid`` False on padding lanes)."""
+    scores = _adc_scores(lut, cand_codes)
+    return jax.lax.top_k(jnp.where(valid[None, :], scores, -jnp.inf), k)
 
 
 @functools.partial(jax.jit, static_argnames=("k", "metric"))
@@ -217,7 +247,7 @@ def _rescore_topk(queries: jnp.ndarray, cand_rows: jnp.ndarray,
     candidate rows (B, R, d), invalid (-1 padded) lanes masked to -inf."""
     scores = jax.lax.dot_general(
         cand_rows, queries, (((2,), (1,)), ((0,), (0,))),
-        preferred_element_type=jnp.float32)                   # (B, R)
+        precision=EXACT, preferred_element_type=jnp.float32)  # (B, R)
     if metric == "l2":
         scores = 2.0 * scores - jnp.sum(cand_rows * cand_rows, axis=-1)
     scores = jnp.where(valid, scores, -jnp.inf)
@@ -247,9 +277,13 @@ def gather_rescore(store: VectorStore, queries: np.ndarray,
         store.rescore_fetch_bytes += n_fetch * store.dim * 4
     rows = store.fetch_rows(np.maximum(cand_ids, 0))         # (B, R, d)
     kk = min(k, cand_ids.shape[1])
-    vals, loc = _rescore_topk(jnp.asarray(queries), jnp.asarray(rows),
-                              jnp.asarray(cand_ids >= 0), kk, store.metric)
-    vals = np.asarray(vals, dtype=np.float32)
+    b, bp = len(queries), bucket(len(queries))
+    vals, loc = _rescore_topk(jnp.asarray(pad_rows(queries, bp)),
+                              jnp.asarray(pad_rows(rows, bp)),
+                              jnp.asarray(pad_rows(cand_ids >= 0, bp)), kk,
+                              store.metric)
+    vals = np.asarray(vals, dtype=np.float32)[:b]
+    loc = np.asarray(loc)[:b]
     ids = np.take_along_axis(cand_ids, np.asarray(loc, dtype=np.int64),
                              axis=1)
     ids[~np.isfinite(vals)] = -1
@@ -258,19 +292,15 @@ def gather_rescore(store: VectorStore, queries: np.ndarray,
 
 @functools.partial(jax.jit, static_argnames=("k", "metric"))
 def _gather_topk(queries: jnp.ndarray, cand_rows: jnp.ndarray,
+                 valid: jnp.ndarray,
                  k: int, metric: str) -> Tuple[jnp.ndarray, jnp.ndarray]:
-    if cand_rows.shape[0] == 1:
-        # XLA lowers the (B, d) @ (d, 1) case to a gemv whose accumulation
-        # order depends on B; the elementwise-sum form is batch-invariant,
-        # which dsq_batch needs to stay bit-identical to per-request dsq.
-        scores = jnp.sum(queries * cand_rows[0][None, :], axis=-1,
-                         keepdims=True)
-    else:
-        scores = queries @ cand_rows.T
+    """Exact fp32 gather plan over candidate rows (``valid`` False on
+    padding lanes)."""
+    scores = exact_scores(queries, cand_rows)
     if metric == "l2":
         scores = 2.0 * scores - jnp.sum(
             cand_rows * cand_rows, axis=-1)[None, :]
-    return jax.lax.top_k(scores, k)
+    return jax.lax.top_k(jnp.where(valid[None, :], scores, -jnp.inf), k)
 
 
 class FlatExecutor:
@@ -325,37 +355,44 @@ class FlatExecutor:
             if not (plan == "gather" and m <= r):
                 return self._search_pq(queries, k, candidate_ids, plan, r)
         kk = min(k, m)
+        b = len(queries)
+        qp = jnp.asarray(pad_rows(queries, bucket(b)))
         if plan == "gather":
-            cand_rows = self.store.vectors[candidate_ids]
+            mp = bucket(m)
             scores, local = _gather_topk(
-                jnp.asarray(queries), jnp.asarray(cand_rows), kk,
-                self.store.metric)
-            ids = candidate_ids[np.asarray(local)]
+                qp, jnp.asarray(pad_rows(self.store.vectors[candidate_ids],
+                                         mp)),
+                jnp.asarray(np.arange(mp) < m), kk, self.store.metric)
+            ids = candidate_ids[np.asarray(local)[:b]]
         else:
             words = pack_ids_to_words(candidate_ids, n)
             scores, ids = _scan_topk(
-                jnp.asarray(queries), self.store.device_vectors(),
-                self._sq(), jnp.asarray(words), kk, self.store.metric)
-            ids = np.asarray(ids)
-        return pad_topk(np.asarray(scores), ids, k)
+                qp, self.store.device_vectors(), self._sq(),
+                jnp.asarray(words), kk, self.store.metric)
+            ids = np.asarray(ids)[:b]
+        return pad_topk(np.asarray(scores)[:b], ids, k)
 
     def _search_int8(self, queries: np.ndarray, k: int,
                      candidate_ids: np.ndarray, plan: str, r: int
                      ) -> Tuple[np.ndarray, np.ndarray]:
         """Two-phase int8 path of :meth:`search` (r = effective rescore_k)."""
-        n = len(self.store)
-        q_i8, q_s = quantize_rows(queries)
+        n, b = len(self.store), len(queries)
+        q_i8, q_s = quantize_rows(pad_rows(queries, bucket(b)))
         if plan == "gather":
-            cand_i8 = self.store.q_vectors[candidate_ids]
-            cand_sc = self.store.q_scales[candidate_ids]
-            cand_sq = (self.store.q_sq_norms()[candidate_ids]
+            m = len(candidate_ids)
+            mp = bucket(m)
+            cand_sq = (pad_rows(self.store.q_sq_norms()[candidate_ids], mp)
                        if self.store.metric == "l2"
                        else np.zeros(0, np.float32))
             _, local = _gather_topk_i8(
-                jnp.asarray(q_i8), jnp.asarray(q_s), jnp.asarray(cand_i8),
-                jnp.asarray(cand_sc), jnp.asarray(cand_sq), r,
+                jnp.asarray(q_i8), jnp.asarray(q_s),
+                jnp.asarray(pad_rows(self.store.q_vectors[candidate_ids],
+                                     mp)),
+                jnp.asarray(pad_rows(self.store.q_scales[candidate_ids], mp)),
+                jnp.asarray(cand_sq), jnp.asarray(np.arange(mp) < m), r,
                 self.store.metric)
-            cand = np.asarray(candidate_ids, np.int64)[np.asarray(local)]
+            cand = np.asarray(candidate_ids, np.int64)[
+                np.asarray(local)[:b]]
         else:
             words = pack_ids_to_words(candidate_ids, n)
             vals, cand = _scan_topk_i8(
@@ -363,10 +400,10 @@ class FlatExecutor:
                 self.store.device_q_vectors(), self.store.device_q_scales(),
                 self._q_sq(), jnp.asarray(words), min(r, n),
                 self.store.metric)
-            cand = np.asarray(cand, dtype=np.int64)
+            cand = np.asarray(cand, dtype=np.int64)[:b]
             # top_k hands exhausted (-inf) lanes arbitrary column ids — they
             # are out-of-scope rows and must not reach the rescore
-            cand[~np.isfinite(np.asarray(vals))] = -1
+            cand[~np.isfinite(np.asarray(vals)[:b])] = -1
         return gather_rescore(self.store, queries, cand, k)
 
     def _search_pq(self, queries: np.ndarray, k: int,
@@ -374,20 +411,25 @@ class FlatExecutor:
                    ) -> Tuple[np.ndarray, np.ndarray]:
         """Two-phase PQ path of :meth:`search`: ADC scan/gather over the
         uint8 codes selects ``r`` candidates, exact fp32 rescore ranks k."""
-        n = len(self.store)
-        lut = jnp.asarray(self.store.pq_lut(queries))
+        n, b = len(self.store), len(queries)
+        lut = jnp.asarray(self.store.pq_lut(pad_rows(queries, bucket(b))))
         if plan == "gather":
-            cand_codes = self.store.pq_codes[candidate_ids]
-            _, local = _gather_topk_pq(lut, jnp.asarray(cand_codes), r)
-            cand = np.asarray(candidate_ids, np.int64)[np.asarray(local)]
+            m = len(candidate_ids)
+            mp = bucket(m)
+            _, local = _gather_topk_pq(
+                lut, jnp.asarray(pad_rows(self.store.pq_codes[candidate_ids],
+                                          mp)),
+                jnp.asarray(np.arange(mp) < m), r)
+            cand = np.asarray(candidate_ids, np.int64)[
+                np.asarray(local)[:b]]
         else:
             words = pack_ids_to_words(candidate_ids, n)
             vals, cand = _scan_topk_pq(lut, self.store.device_pq_codes(),
                                        jnp.asarray(words), min(r, n))
-            cand = np.asarray(cand, dtype=np.int64)
+            cand = np.asarray(cand, dtype=np.int64)[:b]
             # exhausted (-inf) lanes carry arbitrary top_k column ids — out
             # of scope, keep them away from the rescore
-            cand[~np.isfinite(np.asarray(vals))] = -1
+            cand[~np.isfinite(np.asarray(vals)[:b])] = -1
         return gather_rescore(self.store, queries, cand, k)
 
     def search_multi(self, queries: np.ndarray, mask_words: np.ndarray,
@@ -399,80 +441,70 @@ class FlatExecutor:
         packed masks (n_scopes, ceil(n/32)), per-query scope row ids (B,).
         Returns (scores, ids) both (B, k), ids int64, -1 where the scope had
         no candidate. The default jnp twin of the Pallas ``multi_scope_topk``
-        keeps results bit-identical to the per-request scan path on every
-        backend; pass ``use_pallas=True`` on real TPUs for the fused kernel
-        (same top-k set, but tie order/low score bits may differ from the
-        unfused jax.lax.top_k). ``precision="int8"`` swaps phase 1 to the
-        quantized-store scan (``multi_scope_topk_i8`` fused, or its jnp
-        twin) and finishes with the shared exact fp32 rescore."""
+        keeps results bit-identical to the per-request scan path; pass
+        ``use_pallas=True`` on real TPUs for the fused kernel (same top-k
+        set, but tie order/low score bits may differ from the unfused
+        jax.lax.top_k). ``precision="int8"`` swaps
+        phase 1 to the quantized-store scan (``multi_scope_topk_i8`` fused,
+        or its jnp twin) and finishes with the shared exact fp32 rescore.
+        Queries and mask rows pad to :func:`bucket` sizes, so batches of
+        any shape reuse a few compiled launches."""
         from ..kernels import ops as kops
         queries = np.atleast_2d(np.asarray(queries, dtype=np.float32))
-        if precision == "int8":
-            return self._search_multi_int8(queries, mask_words, scope_ids,
-                                           k, use_pallas, rescore_k)
-        if precision == "pq":
-            return self._search_multi_pq(queries, mask_words, scope_ids,
-                                         k, use_pallas, rescore_k)
+        b = len(queries)
+        qp = pad_rows(queries, bucket(b))
+        words = jnp.asarray(pad_rows(np.asarray(mask_words, np.uint32),
+                                     bucket(len(mask_words))))
+        sids = jnp.asarray(pad_rows(np.asarray(scope_ids, np.int32),
+                                    bucket(b)))
+        if precision in ("int8", "pq"):
+            r = resolve_rescore_k(k, rescore_k, len(self.store))
+            phase1 = (self._scan_multi_int8 if precision == "int8"
+                      else self._scan_multi_pq)
+            vals, cand = phase1(qp, words, sids, r, use_pallas)
+            cand = np.asarray(cand, dtype=np.int64)[:b]
+            # exhausted (-inf) lanes carry arbitrary top_k column ids (the
+            # fused kernels already yield -1); mask them out of the rescore
+            cand[~np.isfinite(np.asarray(vals)[:b])] = -1
+            return gather_rescore(self.store, queries, cand, k)
         if use_pallas:
             scores, ids = kops.multi_scope_topk(
-                queries, self.store.device_vectors(), mask_words,
-                scope_ids, k=k, metric=self.store.metric)
+                qp, self.store.device_vectors(), words, sids, k=k,
+                metric=self.store.metric, sq=self._sq())
         else:
             scores, ids = _multi_scan_topk(
-                jnp.asarray(queries), self.store.device_vectors(),
-                self._sq(), jnp.asarray(mask_words, dtype=jnp.uint32),
-                jnp.asarray(scope_ids, dtype=jnp.int32), k,
-                self.store.metric)
-        scores = np.asarray(scores)
-        ids = np.asarray(ids, dtype=np.int64)
+                jnp.asarray(qp), self.store.device_vectors(), self._sq(),
+                words, sids, k, self.store.metric)
+        scores = np.asarray(scores)[:b]
+        ids = np.asarray(ids, dtype=np.int64)[:b]
         ids[~np.isfinite(scores)] = -1
         return scores, ids
 
-    def _search_multi_int8(self, queries, mask_words, scope_ids, k,
-                           use_pallas, rescore_k
-                           ) -> Tuple[np.ndarray, np.ndarray]:
+    def _scan_multi_int8(self, queries, words, sids, r, use_pallas):
+        """int8 scan phase of :meth:`search_multi`: (vals, cand) (B, r)."""
         from ..kernels import ops as kops
-        n = len(self.store)
-        r = resolve_rescore_k(k, rescore_k, n)
         q_i8, q_s = quantize_rows(queries)
         if use_pallas:
             # the kernel streams the sq tile unconditionally; hand it a
             # device zeros vector on the metrics that never read it
             sq = (self.store.device_q_sq_norms()
-                  if self.store.metric == "l2" else jnp.zeros(n, jnp.float32))
-            vals, cand = kops.multi_scope_topk_i8(
+                  if self.store.metric == "l2"
+                  else jnp.zeros(len(self.store), jnp.float32))
+            return kops.multi_scope_topk_i8(
                 q_i8, q_s, self.store.device_q_vectors(),
-                self.store.device_q_scales(), sq, mask_words, scope_ids,
-                k=r, metric=self.store.metric)
-        else:
-            vals, cand = _multi_scan_topk_i8(
-                jnp.asarray(q_i8), jnp.asarray(q_s),
-                self.store.device_q_vectors(), self.store.device_q_scales(),
-                self._q_sq(), jnp.asarray(mask_words, dtype=jnp.uint32),
-                jnp.asarray(scope_ids, dtype=jnp.int32), r,
-                self.store.metric)
-        cand = np.asarray(cand, dtype=np.int64)
-        # exhausted (-inf) lanes carry arbitrary top_k column ids (the fused
-        # kernel already yields -1); mask them out of the rescore
-        cand[~np.isfinite(np.asarray(vals))] = -1
-        return gather_rescore(self.store, queries, cand, k)
+                self.store.device_q_scales(), sq, words, sids, k=r,
+                metric=self.store.metric)
+        return _multi_scan_topk_i8(
+            jnp.asarray(q_i8), jnp.asarray(q_s),
+            self.store.device_q_vectors(), self.store.device_q_scales(),
+            self._q_sq(), words, sids, r, self.store.metric)
 
-    def _search_multi_pq(self, queries, mask_words, scope_ids, k,
-                         use_pallas, rescore_k
-                         ) -> Tuple[np.ndarray, np.ndarray]:
+    def _scan_multi_pq(self, queries, words, sids, r, use_pallas):
+        """PQ/ADC scan phase of :meth:`search_multi`: (vals, cand) (B, r)."""
         from ..kernels import ops as kops
-        n = len(self.store)
-        r = resolve_rescore_k(k, rescore_k, n)
         lut = self.store.pq_lut(queries)
         if use_pallas:
-            vals, cand = kops.multi_scope_topk_pq(
-                lut, self.store.device_pq_codes(), mask_words, scope_ids,
-                k=r)
-        else:
-            vals, cand = _multi_scan_topk_pq(
-                jnp.asarray(lut), self.store.device_pq_codes(),
-                jnp.asarray(mask_words, dtype=jnp.uint32),
-                jnp.asarray(scope_ids, dtype=jnp.int32), r)
-        cand = np.asarray(cand, dtype=np.int64)
-        cand[~np.isfinite(np.asarray(vals))] = -1
-        return gather_rescore(self.store, queries, cand, k)
+            return kops.multi_scope_topk_pq(
+                lut, self.store.device_pq_codes(), words, sids, k=r)
+        return _multi_scan_topk_pq(
+            jnp.asarray(lut), self.store.device_pq_codes(), words, sids, r)

@@ -5,9 +5,11 @@ K-means (Lloyd) runs as a jit'd JAX loop. Partitions live in a device-resident
 TILE-aligned region (padding slots hold the invalid id ``n``), plus per-list
 offsets/lengths. Search is batched end to end — query→centroid distances and
 ``nprobe`` selection for the whole batch in one jit, then a single
-gather→score→top-k launch over the probed tiles with the directory scope
-applied as packed uint32 mask words ANDed in-register (either the jnp twin
-``_ivf_batch_jnp`` or the Pallas ``ivf_gather_topk`` kernel).
+score→top-k launch over the probed lists with the directory scope applied as
+packed uint32 mask words: either the jnp twin ``_ivf_batch_jnp``, which
+gathers the probed tiles, or the Pallas ``ivf_gather_topk`` kernel, which
+streams each query's probed lists from a list-major device copy of the rows
+(:class:`ListBlocks`) by block index.
 
 The paper's finding that IVF shows a *flat* latency-vs-depth profile (Fig. 11)
 falls out naturally: partition probing dominates and the scope intersection is
@@ -28,11 +30,13 @@ import jax.numpy as jnp
 from .costmodel import model_of
 from .store import VectorStore
 
-# Per-list padding granularity of the CSR layout. The fused launch expands
+# Per-list padding granularity of the CSR layout. The jnp launch expands
 # every probed list to the layout's widest padded region, so a small tile
-# keeps that expansion tight; the kernel streams the *gathered* (contiguous)
-# candidate tiles, so list-region alignment never touches TPU lane tiling.
+# keeps that expansion tight.
 TILE = 32
+# Rows per block of the list-major store the Pallas IVF scan streams (a
+# multiple of the 128-lane tiling; one 1 MB DMA per block at d=1024).
+LIST_BLOCK = 256
 
 
 @functools.partial(jax.jit, static_argnames=("n_iters",))
@@ -81,14 +85,40 @@ class CSRLayout:
     n: int                   # store size the sentinel was built for
 
 
-def _probe_and_expand(queries, centers, offsets, aligned, flat_ids,
-                      nprobe: int, max_aligned: int):
-    """Whole-batch probe selection + candidate-tile expansion. Centroid
+@dataclass(frozen=True)
+class ListBlocks:
+    """Device-resident list-major copy of the store for the Pallas IVF
+    scan: list ``c`` is blocks ``start[c] .. start[c] + nblk[c] - 1`` of
+    ``LIST_BLOCK`` rows each (at least one block; the tail holds id -1 and
+    zero rows). The kernel streams a query's probed lists from here by
+    block index, so the copy costs one store's worth of device memory and
+    no query ever materializes its candidate rows."""
+    rows: jnp.ndarray        # (n_blocks * LIST_BLOCK, d)
+    ids: jnp.ndarray         # (n_blocks, LIST_BLOCK) int32, -1 = padding
+    sq: jnp.ndarray          # (n_blocks, LIST_BLOCK) f32 (zeros unless l2)
+    start: jnp.ndarray       # (n_lists,) int32
+    nblk: jnp.ndarray        # (n_lists,) int32
+    max_blocks: int          # static: the longest list's block count
+    layout: "CSRLayout"      # the CSR layout this copy was built from
+
+
+def _probe(queries, centers, nprobe: int):
+    """(B, nprobe) nearest lists per query, nearest first. Centroid
     distances use the elementwise (q-c)^2 form so every element depends only
     on its own (query, center) pair — batch-size invariant, which keeps
     dsq_batch bit-identical to the per-request loop."""
     d2 = jnp.sum((queries[:, None, :] - centers[None, :, :]) ** 2, axis=-1)
-    _, probe = jax.lax.top_k(-d2, nprobe)                 # (B, nprobe)
+    return jax.lax.top_k(-d2, nprobe)[1]
+
+
+_probe_jit = jax.jit(_probe, static_argnames=("nprobe",))
+
+
+def _probe_and_expand(queries, centers, offsets, aligned, flat_ids,
+                      nprobe: int, max_aligned: int):
+    """Whole-batch probe selection + candidate-tile expansion: (B, C) store
+    ids of the probed lists' CSR regions, padding = the invalid id n."""
+    probe = _probe(queries, centers, nprobe)              # (B, nprobe)
     off = jnp.take(offsets, probe)                        # (B, nprobe)
     algn = jnp.take(aligned, probe)
     within = jnp.arange(max_aligned, dtype=jnp.int32)
@@ -119,6 +149,7 @@ def _ivf_batch_jnp(queries, centers, offsets, aligned, flat_ids, data, sq,
     rows = jnp.take(data, safe, axis=0)                   # (B, C, d)
     scores = jax.lax.dot_general(
         rows, queries, (((2,), (1,)), ((0,), (0,))),
+        precision=jax.lax.Precision.HIGHEST,
         preferred_element_type=jnp.float32)               # (B, C)
     if metric == "l2":
         scores = 2.0 * scores - jnp.take(sq, safe)
@@ -198,19 +229,15 @@ def _ivf_batch_pq(queries, lut, centers, offsets, aligned, flat_ids,
     return vals, ids
 
 
-@functools.partial(jax.jit, static_argnames=("nprobe", "max_aligned"))
-def _ivf_expand_gather(queries, centers, offsets, aligned, flat_ids, data,
-                       words, sids, nprobe: int, max_aligned: int):
-    """Pallas-path front half: probe + candidate expansion + row/word gather.
-    Returns (cand (B, C) int32 with -1 invalid, rows (B, C, d),
-    qwords (B, n_words))."""
-    n = data.shape[0]
-    cand = _probe_and_expand(queries, centers, offsets, aligned, flat_ids,
-                             nprobe, max_aligned)
-    cand = jnp.where(cand < n, cand, -1)
-    rows = jnp.take(data, jnp.maximum(cand, 0), axis=0)
-    qwords = jnp.take(words, sids, axis=0)
-    return cand, rows, qwords
+@jax.jit
+def _list_major(vectors, sq, ids):
+    """List-major copy of the store rows (zero rows on padding) and their
+    squared norms, both addressed by the (n_blocks, block) id table."""
+    safe = jnp.maximum(ids, 0)
+    live = ids >= 0
+    rows = jnp.where(live.reshape(-1, 1),
+                     jnp.take(vectors, safe.reshape(-1), axis=0), 0)
+    return rows, jnp.where(live, jnp.take(sq, safe), 0.0)
 
 
 class IVFIndex:
@@ -246,6 +273,7 @@ class IVFIndex:
             self._len[c] = len(members)
         self.assign = assign
         self._layout: Optional[CSRLayout] = None
+        self._blocks: Optional[ListBlocks] = None
         self._centers_dev: Optional[jnp.ndarray] = None
         # bumped by every completed repartition(); the maintenance journal's
         # idempotence probe on crash replay
@@ -277,6 +305,7 @@ class IVFIndex:
         for c in np.unique(assign):
             self._append(int(c), ids[assign == c])
         self._layout = None
+        self._blocks = None
 
     def layout(self) -> CSRLayout:
         """Build (or reuse) the device-resident padded-CSR layout."""
@@ -297,6 +326,41 @@ class IVFIndex:
                 max_aligned=int(aligned.max()) if self.n_lists else 0,
                 n=n)
         return self._layout
+
+    def list_blocks(self) -> ListBlocks:
+        """Build (or reuse) the list-major device copy of the rows, in step
+        with :meth:`layout` (rebuilt whenever the layout is)."""
+        lay = self.layout()
+        if self._blocks is None or self._blocks.layout is not lay:
+            self._blocks = None            # free the old copy first
+            nblk = np.maximum(1, -(-self._len // LIST_BLOCK))
+            start = np.zeros(self.n_lists, dtype=np.int64)
+            np.cumsum(nblk[:-1], out=start[1:])
+            ids = np.full((int(nblk.sum()), LIST_BLOCK), -1, dtype=np.int32)
+            flat = ids.reshape(-1)
+            for c in range(self.n_lists):
+                ln = int(self._len[c])
+                off = int(start[c]) * LIST_BLOCK
+                flat[off: off + ln] = self._data[c][:ln]
+            sq = (self.store.device_sq_norms() if self.store.metric == "l2"
+                  else jnp.zeros(max(len(self.store), 1), jnp.float32))
+            ids_d = jnp.asarray(ids)
+            rows, sq_lm = _list_major(self.store.device_vectors(), sq, ids_d)
+            self._blocks = ListBlocks(
+                rows=rows, ids=ids_d, sq=sq_lm,
+                start=jnp.asarray(start.astype(np.int32)),
+                nblk=jnp.asarray(nblk.astype(np.int32)),
+                max_blocks=int(nblk.max()), layout=lay)
+        return self._blocks
+
+    def probe(self, queries: np.ndarray, nprobe: int) -> np.ndarray:
+        """(B, nprobe) lists each query probes, nearest first — the device
+        probe of every batched search."""
+        if self._centers_dev is None:
+            self._centers_dev = jnp.asarray(self.centers)
+        return np.asarray(_probe_jit(
+            jnp.asarray(np.atleast_2d(queries), jnp.float32),
+            self._centers_dev, nprobe=int(nprobe)))
 
     def nbytes(self) -> int:
         return self.centers.nbytes + sum(d.nbytes for d in self._data)
@@ -379,6 +443,7 @@ class IVFIndex:
         self._len = new_len
         self.assign = assign
         self._layout = None
+        self._blocks = None
         self._centers_dev = None
         self.repartition_gen += 1
         return {"gen": self.repartition_gen, "moved": moved,
@@ -402,6 +467,7 @@ class IVFIndex:
         new_n = int(np.sum(m >= 0))
         self.assign = self._current_assign(new_n)
         self._layout = None
+        self._blocks = None
 
     # ----------------------------------------------------------------- search
     def search(self, queries: np.ndarray, k: int,
@@ -489,23 +555,29 @@ class IVFIndex:
             return gather_rescore(self.store, queries,
                                   np.asarray(cand, dtype=np.int64), k)
         kk = min(k, C)
-        args = (jnp.asarray(queries), self._centers_dev,
-                lay.offsets, lay.aligned, lay.flat_ids,
-                self.store.device_vectors())
-        # sq is only read on the (trace-time static) l2 branch; skip the O(n)
-        # host→device transfer entirely for ip/cos
-        sq = (self.store.device_sq_norms() if self.store.metric == "l2"
-              else jnp.zeros(0, dtype=jnp.float32))
         if use_pallas:
             from ..kernels import ops as kops
-            cand, rows, qwords = _ivf_expand_gather(
-                *args, words_d, sids_d, nprobe=nprobe,
-                max_aligned=lay.max_aligned)
-            vals, ids = kops.ivf_gather_topk(queries, rows, cand, qwords,
-                                             k=kk, metric=self.store.metric)
+            from .flat import bucket, pad_rows
+            blocks = self.list_blocks()
+            bp = bucket(B)
+            qp = jnp.asarray(pad_rows(queries, bp))
+            probe = _probe_jit(qp, self._centers_dev, nprobe=nprobe)
+            qwords = jnp.take(words_d, jnp.asarray(
+                pad_rows(np.asarray(scope_ids, np.int32), bp)), axis=0)
+            vals, ids = kops.ivf_gather_topk(
+                qp, probe, blocks.start, blocks.nblk, blocks.rows,
+                blocks.ids, blocks.sq, qwords, max_blocks=blocks.max_blocks,
+                k=kk, metric=self.store.metric)
+            vals, ids = vals[:B], ids[:B]
         else:
+            # sq is only read on the (trace-time static) l2 branch; skip the
+            # O(n) host→device transfer entirely for ip/cos
+            sq = (self.store.device_sq_norms() if self.store.metric == "l2"
+                  else jnp.zeros(0, dtype=jnp.float32))
             vals, ids = _ivf_batch_jnp(
-                *args, sq, words_d, sids_d, k=kk, nprobe=nprobe,
+                jnp.asarray(queries), self._centers_dev, lay.offsets,
+                lay.aligned, lay.flat_ids, self.store.device_vectors(), sq,
+                words_d, sids_d, k=kk, nprobe=nprobe,
                 max_aligned=lay.max_aligned, metric=self.store.metric)
         vals = np.array(vals, dtype=np.float32)
         ids = np.asarray(ids, dtype=np.int64)

@@ -22,8 +22,11 @@ dequantization is total (no divide-by-zero, no NaN scores).
 """
 from __future__ import annotations
 
+import math
 from typing import Optional, Tuple
 
+import jax
+import jax.numpy as jnp
 import numpy as np
 
 # int8 scan phase keeps this many candidates per query (times k) before the
@@ -51,6 +54,17 @@ def dequantize_rows(codes: np.ndarray, scales: np.ndarray) -> np.ndarray:
     """Inverse of :func:`quantize_rows`: ``codes * scale`` per row, fp32."""
     return codes.astype(np.float32) * np.asarray(
         scales, dtype=np.float32)[:, None]
+
+
+def pq_rescore_factor(m: int) -> int:
+    """Default PQ rescore window per k: ``DEFAULT_RESCORE_FACTOR`` up to 16
+    subspaces, then growing with sqrt(M). A row's ADC score sums M
+    independent subspace quantization errors, so its error grows like
+    sqrt(M) while the gap between a scope's k-th and (k+1)-th exact scores
+    does not; at the default dsub = 4 that is 4 below 64-d, 16 at 1024-d
+    (M = 256, where a 4k window reached recall@10 0.891 on a near-isotropic
+    WIKI-Dir twin and 16k reached 0.992 on a TPU v5e)."""
+    return DEFAULT_RESCORE_FACTOR * max(1, math.ceil(math.sqrt(m / 16)))
 
 
 def resolve_rescore_k(k: int, rescore_k: Optional[int], n: int) -> int:
@@ -87,6 +101,8 @@ def resolve_rescore_k(k: int, rescore_k: Optional[int], n: int) -> int:
 PQ_N_CENTROIDS = 256
 PQ_TRAIN_SAMPLE = 4096
 PQ_TRAIN_ITERS = 10
+# elements of one (rows, M, 256) distance tile in the device assignment
+PQ_ASSIGN_TILE = 1 << 26
 
 
 def default_pq_m(dim: int) -> int:
@@ -97,6 +113,34 @@ def default_pq_m(dim: int) -> int:
         if dim % m == 0:
             return m
     return 1
+
+
+def _nearest_centroids(sub: np.ndarray, cents: np.ndarray) -> np.ndarray:
+    """Nearest centroid of every (row, subspace): ``sub`` (n, M, dsub),
+    ``cents`` (M, 256, dsub) -> (n, M) int64. Runs on the default device in
+    row chunks of a power-of-two size (few compiled shapes) that bound the
+    (chunk, M, 256) distance tile."""
+    n, m, _ = sub.shape
+    chunk = max(1, min(PQ_ASSIGN_TILE // (m * cents.shape[1]),
+                       1 << max(n - 1, 0).bit_length()))
+    cents_d = jnp.asarray(cents)
+    out = np.empty((n, m), np.int64)
+    for lo in range(0, n, chunk):
+        part = sub[lo: lo + chunk]
+        got = len(part)
+        part = np.pad(part, [(0, chunk - got), (0, 0), (0, 0)])
+        out[lo: lo + got] = np.asarray(
+            _pq_assign(jnp.asarray(part), cents_d))[:got]
+    return out
+
+
+@jax.jit
+def _pq_assign(sub, cents):
+    # argmin |x - c|^2 == argmin |c|^2 - 2 x.c  (drop the |x|^2 term)
+    dots = jnp.einsum("nmd,mcd->nmc", sub, cents,
+                      precision=jax.lax.Precision.HIGHEST)
+    d2 = jnp.sum(cents * cents, axis=-1)[None] - 2.0 * dots
+    return jnp.argmin(d2, axis=-1).astype(jnp.int32)
 
 
 class PQCodebook:
@@ -131,7 +175,9 @@ class PQCodebook:
 
     def train(self, rows: np.ndarray) -> None:
         """Lloyd k-means per subspace on (a sample of) ``rows``; empty
-        clusters keep their previous centroid (the IVF trainer's rule)."""
+        clusters keep their previous centroid (the IVF trainer's rule).
+        Every subspace runs its iterations in lockstep: one device
+        assignment over all subspaces per iteration."""
         rows = np.atleast_2d(np.asarray(rows, dtype=np.float32))
         rng = np.random.default_rng(self.seed)
         n = len(rows)
@@ -139,36 +185,30 @@ class PQCodebook:
             rows = rows[rng.choice(n, size=PQ_TRAIN_SAMPLE, replace=False)]
             n = PQ_TRAIN_SAMPLE
         k = PQ_N_CENTROIDS
-        cents = np.empty((self.m, k, self.dsub), np.float32)
-        for m in range(self.m):
-            sub = rows[:, m * self.dsub:(m + 1) * self.dsub]
-            init = rng.choice(n, size=k, replace=n < k)
-            c = sub[init].copy()
-            for _ in range(PQ_TRAIN_ITERS):
-                assign = self._assign(sub, c)
-                counts = np.bincount(assign, minlength=k).astype(np.float32)
-                sums = np.zeros_like(c)
-                np.add.at(sums, assign, sub)
-                nonempty = counts > 0
-                c[nonempty] = sums[nonempty] / counts[nonempty, None]
-            cents[m] = c
+        sub = rows.reshape(n, self.m, self.dsub)
+        cents = np.stack([sub[rng.choice(n, size=k, replace=n < k), m]
+                          for m in range(self.m)])          # (M, k, dsub)
+        # flat (subspace, centroid) slot of every (row, subspace) pair
+        base = np.arange(self.m, dtype=np.int64)[None, :] * k
+        flat_sub = sub.reshape(-1, self.dsub)
+        for _ in range(PQ_TRAIN_ITERS):
+            slot = (base + _nearest_centroids(sub, cents)).ravel()
+            counts = np.bincount(slot, minlength=self.m * k)
+            sums = np.stack([np.bincount(slot, weights=flat_sub[:, j],
+                                         minlength=self.m * k)
+                             for j in range(self.dsub)], axis=1)
+            nonempty = counts > 0
+            flat_c = cents.reshape(-1, self.dsub)
+            flat_c[nonempty] = (sums[nonempty]
+                                / counts[nonempty, None]).astype(np.float32)
         self.centroids = cents
-
-    @staticmethod
-    def _assign(sub: np.ndarray, cents: np.ndarray) -> np.ndarray:
-        # argmin |x - c|^2 == argmin |c|^2 - 2 x.c  (drop the |x|^2 term)
-        d2 = (cents * cents).sum(axis=1)[None, :] - 2.0 * (sub @ cents.T)
-        return np.argmin(d2, axis=1)
 
     def encode(self, rows: np.ndarray) -> np.ndarray:
         """Nearest-centroid codes, ``(n, M) uint8``."""
         self._require_trained()
         rows = np.atleast_2d(np.asarray(rows, dtype=np.float32))
-        out = np.empty((len(rows), self.m), np.uint8)
-        for m in range(self.m):
-            sub = rows[:, m * self.dsub:(m + 1) * self.dsub]
-            out[:, m] = self._assign(sub, self.centroids[m]).astype(np.uint8)
-        return out
+        sub = rows.reshape(len(rows), self.m, self.dsub)
+        return _nearest_centroids(sub, self.centroids).astype(np.uint8)
 
     def decode(self, codes: np.ndarray) -> np.ndarray:
         """Reconstruct ``(n, dim)`` fp32 rows from codes."""
@@ -212,8 +252,6 @@ def int_exact_dot(a_i8, b_i8, dnums=(((1,), (1,)), ((), ())),
     whose int8 path is a scalar loop (CPU XLA). Past the bound it falls
     back to true int32 accumulation. ``contract_dim`` defaults to the last
     axis of ``a_i8`` (pass it explicitly for exotic dnums)."""
-    import jax
-    import jax.numpy as jnp
     d = a_i8.shape[-1] if contract_dim is None else contract_dim
     if d * Q_MAX * Q_MAX < (1 << 24):
         return jax.lax.dot_general(

@@ -23,9 +23,11 @@ Gather-plan groups (selective scopes, |C| << N) stay on the single-device
 gather launch — a full mesh sweep for a 50-row scope would waste every
 shard — by delegating to the shared :class:`FlatExecutor` machinery, which
 also keeps the batch bit-identical to the flat path by construction. The
-scan side is bit-identical because the per-shard scoring expression is
-textually the flat twin's and top-k tie order is preserved by the
-shard-order merge (ties resolve to the lowest global id on both paths).
+scan side scores with the flat twin's full-precision fp32 product and
+keeps top-k tie order through the shard-order merge (ties resolve to the
+lowest global id on both paths); its scores may differ from the flat
+path's by a few fp32 ULP, because XLA rounds a matmul differently per
+operand shape and a shard sees fewer rows.
 """
 from __future__ import annotations
 
@@ -40,7 +42,8 @@ import jax
 import jax.numpy as jnp
 
 from .costmodel import model_of
-from .flat import FlatExecutor, choose_plan, gather_rescore, pad_topk
+from .flat import (FlatExecutor, bucket, choose_plan, gather_rescore,
+                   pad_rows, pad_topk)
 from .quant import quantize_rows, resolve_rescore_k
 from .store import ShardedStoreView, VectorStore, pack_ids_to_words
 
@@ -336,15 +339,19 @@ class ShardedExecutor:
         candidates, the shard-merge replicates the global rescore_k set, and
         ONE exact fp32 gather-rescore on the host store ranks the final k."""
         queries = np.atleast_2d(np.asarray(queries, dtype=np.float32))
-        if precision == "int8":
+        # pad the batch to a bucket size: a few compiled launches serve
+        # every batch shape (padding rows read slot 0 and are dropped)
+        b = len(queries)
+        qp = pad_rows(queries, bucket(b))
+        sids = pad_rows(np.asarray(slot_ids, np.int32), bucket(b))
+        if precision in ("int8", "pq"):
             r = self.phase_depth(k, precision, rescore_k)
-            cand = self._launch_i8(queries, self._table, slot_ids, r)
+            launch = self._launch_i8 if precision == "int8" else \
+                self._launch_pq
+            cand = launch(qp, self._table, sids, r)[:b]
             return gather_rescore(self.store, queries, cand, k)
-        if precision == "pq":
-            r = self.phase_depth(k, precision, rescore_k)
-            cand = self._launch_pq(queries, self._table, slot_ids, r)
-            return gather_rescore(self.store, queries, cand, k)
-        scores, ids = self._launch(queries, self._table, slot_ids, k)
+        scores, ids = self._launch(qp, self._table, sids, k)
+        scores, ids = scores[:b], ids[:b]
         ids[~np.isfinite(scores)] = -1
         return scores, ids
 
@@ -394,8 +401,9 @@ class ShardedExecutor:
                ) -> Tuple[np.ndarray, np.ndarray]:
         """Single-scope front door, mirroring ``FlatExecutor.search``'s plan
         decision; the scan plan runs on the mesh (an ad-hoc one-row scope
-        table, no slot pinned). Results are bit-identical to the flat
-        executor for any candidate set free of tombstoned ids — which every
+        table, no slot pinned). Results match the flat executor (same ids,
+        scores within a few fp32 ULP) for any candidate set free of
+        tombstoned ids — which every
         DSQ path guarantees, since scope resolution drops deleted entries.
         A stale caller-supplied id set containing tombstones diverges on the
         scan plan only: the mesh ANDs the store tombstone mask in-register,

@@ -21,7 +21,7 @@ import jax
 import jax.numpy as jnp
 
 from .. import faults
-from .quant import PQCodebook, quantize_rows
+from .quant import PQCodebook, default_pq_m, quantize_rows
 
 METRICS = ("ip", "l2", "cos")
 
@@ -444,6 +444,13 @@ class VectorStore:
             self._pq_codes[self._pq_n: self._n] = self._pq.encode(
                 self._rows[self._pq_n: self._n])
             self._pq_n = self._n
+
+    @property
+    def pq_m(self) -> int:
+        """PQ subspace count (known before the codebook is trained)."""
+        if self._pq is not None:
+            return self._pq.m
+        return default_pq_m(self.dim) if self._pq_m is None else self._pq_m
 
     @property
     def pq_codebook(self) -> PQCodebook:

@@ -78,14 +78,11 @@ def test_banded_issues_fewer_flops():
     q = jax.ShapeDtypeStruct((B, S, H, hd), jnp.bfloat16)
     k = jax.ShapeDtypeStruct((B, S, KV, hd), jnp.bfloat16)
     v = jax.ShapeDtypeStruct((B, S, KV, hd), jnp.bfloat16)
-    from repro.compat import cost_analysis_dict
-    full = cost_analysis_dict(
-        jax.jit(lambda q, k, v: naive_attention(q, k, v, causal=True)
-                ).lower(q, k, v).compile())["flops"]
-    band = cost_analysis_dict(
-        jax.jit(lambda q, k, v: local_attention(q, k, v, window=w,
-                                                impl="naive")
-                ).lower(q, k, v).compile())["flops"]
+    full = jax.jit(lambda q, k, v: naive_attention(q, k, v, causal=True)
+                   ).lower(q, k, v).compile().cost_analysis()["flops"]
+    band = jax.jit(lambda q, k, v: local_attention(q, k, v, window=w,
+                                                   impl="naive")
+                   ).lower(q, k, v).compile().cost_analysis()["flops"]
     assert band < full / 3, (band, full)
 
 

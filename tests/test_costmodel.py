@@ -7,10 +7,11 @@ The load-bearing contracts:
 * a heuristic model reproduces the pre-cost-model constants bit-for-bit;
 * measured answers are clamped so recall can only improve (rescore floor,
   nprobe floor, int8->fp32-only precision flips, threshold band);
-* one model per database keeps loop / batch / sharded plans bit-identical
-  for any *fixed* artifact;
-* artifacts from a different backend degrade to the roofline fallback, never
-  to silently-misapplied measurements.
+* one model per database keeps loop / batch / sharded plans identical for
+  any *fixed* artifact;
+* artifacts from a different backend degrade to the roofline fallback —
+  priced with the running device's published peaks, an error on a device
+  without them — never to silently-misapplied measurements.
 """
 import json
 
@@ -26,6 +27,7 @@ from repro.vectordb.costmodel import (ENV_CALIBRATION, GATHER_THRESHOLD,
                                       THRESHOLD_BOUNDS, CalibrationArtifact,
                                       CostModel, model_of,
                                       resolve_calibration)
+from repro.vectordb.parity import topk_mismatch
 from repro.vectordb.quant import DEFAULT_RESCORE_FACTOR
 from repro.vectordb.store import VectorStore
 
@@ -105,8 +107,14 @@ def test_resolve_calibration_sources(tmp_path, monkeypatch):
 
 
 def test_backend_mismatch_degrades_to_roofline():
-    model = resolve_calibration(_artifact(backend="not-a-real-backend"))
+    foreign = CalibrationArtifact(_artifact(backend="not-a-real-backend"))
+    # the roofline source prices with the running device's published
+    # peaks: a device kind without them (this CPU) is an error, not v5e
+    with pytest.raises(ValueError, match="no published peaks"):
+        CostModel.from_artifact(foreign, device_kind="cpu")
+    model = CostModel.from_artifact(foreign, device_kind="TPU v5 lite")
     assert model.source == "roofline"
+    assert model.peaks.hbm_bytes_per_s == 819e9
     # roofline answers: analytic crossover, no tuned blocks, no scheduler
     # defaults, and the measured-only decisions pass caller values through
     assert model.gather_threshold() == pytest.approx(0.125)
@@ -183,6 +191,20 @@ def test_measured_precision_flip_is_upgrade_only():
     assert slow_i8.pick_precision("fp32", 50_000, 10, None) == "fp32"
 
 
+def test_pick_rescore_k_precision_floor():
+    """A precision's hand-set window (PQ's is wider) is the floor under
+    every model: heuristic returns it, measured may only widen it."""
+    heur = CostModel.heuristic()
+    assert heur.pick_rescore_k(10, None, 1000) is None
+    assert heur.pick_rescore_k(10, None, 1000, floor=16) == 160
+    assert heur.pick_rescore_k(10, 25, 1000, floor=16) == 25
+    for factor, want in ((4, 160), (20, 200)):
+        art = CalibrationArtifact(_artifact(rescore_factor=factor))
+        measured = CostModel.from_artifact(art)
+        assert measured.pick_rescore_k(10, None, 1000, floor=16) == want
+        assert measured.pick_rescore_k(10, None, 1000) == max(factor, 4) * 10
+
+
 def test_model_of_defaults_to_heuristic():
     st = VectorStore(DIM, "ip")
     assert model_of(st) is HEURISTIC
@@ -193,7 +215,8 @@ def test_model_of_defaults_to_heuristic():
 # ------------------------------------------------------------ bit-identity
 def test_bit_identity_under_pinned_artifact():
     """Loop dsq, dsq_batch and the sharded executor read ONE model, so a
-    pinned artifact that *changes* plans still keeps them bit-identical."""
+    pinned artifact that *changes* plans still keeps loop and batch
+    bit-identical and sharded within a few fp32 ULP (``vectordb.parity``)."""
     art = _artifact(threshold=0.25)      # 5x the hand-set crossover
     vecs = RNG.normal(size=(1200, DIM)).astype(np.float32)
     paths = (["/a/"] * 140 + ["/b/"] * 30 + ["/c/"] * 1030)
@@ -215,9 +238,8 @@ def test_bit_identity_under_pinned_artifact():
         np.testing.assert_array_equal(res.ids, loop.ids)
         np.testing.assert_array_equal(res.scores, loop.scores)
         sh = cal.dsq_batch(q[i:i + 1], [req[i]], k=10, executor="sharded")[0]
-        np.testing.assert_array_equal(res.ids, sh.ids)
-        np.testing.assert_allclose(res.scores, sh.scores, rtol=1e-5,
-                                   atol=1e-5)
+        err = topk_mismatch(sh.scores, sh.ids, res.scores, res.ids)
+        assert err is None, (i, err)
         # plan changes never change the answer: exact fp32 either way
         np.testing.assert_array_equal(np.sort(res.ids[0]),
                                       np.sort(hb[i].ids[0]))

@@ -13,7 +13,8 @@ pure-Python oracle and against each other:
 * strategies must agree *exactly* with each other and with the oracle on
   every resolved scope (rmdir removal sets included);
 * flat / sharded results must match the oracle's exact top-k (score parity,
-  tie-tolerant id sets) and each other bit-for-bit;
+  tie-tolerant id sets); flat batch results must match the per-request
+  loop bit-for-bit, sharded ones within a few fp32 ULP (``vectordb.parity``);
 * ivf's device path must match its per-query loop oracle, and every
   approximate result (ivf, pg) must stay inside the oracle scope with
   correctly-computed scores;
@@ -31,6 +32,7 @@ import pytest
 from repro.core import DSM, STRATEGIES
 from repro.core import paths as P
 from repro.vectordb import DirectoryVectorDB, MaintenancePolicy
+from repro.vectordb.parity import topk_mismatch
 
 DIM = 16
 K = 5
@@ -519,14 +521,22 @@ class FuzzState:
                         rtol=1e-5, atol=1e-5,
                         err_msg=f"{strat}/{name}/{i}")
                 if name in ("flat", "sharded"):
-                    # batch must be *bit*-identical to the loop here
+                    # flat batch must be *bit*-identical to the loop; the
+                    # sharded loop may score a request through the flat
+                    # path, so it is held to the flat/sharded contract
+                    # (same ids, scores within a few fp32 ULP)
                     for i, res in enumerate(batch):
                         loop = db.dsq(qs[i], paths[i], k=K,
                                       recursive=rec[i], exclude=exc[i],
                                       executor=name)
-                        np.testing.assert_array_equal(res.ids, loop.ids)
-                        np.testing.assert_array_equal(res.scores,
-                                                      loop.scores)
+                        if name == "flat":
+                            np.testing.assert_array_equal(res.ids, loop.ids)
+                            np.testing.assert_array_equal(res.scores,
+                                                          loop.scores)
+                            continue
+                        err = topk_mismatch(res.scores, res.ids,
+                                            loop.scores, loop.ids)
+                        assert err is None, (strat, name, i, err)
 
 
 WEIGHTS = [("ingest", 0.22), ("mkdir", 0.12), ("move", 0.14),

@@ -24,7 +24,7 @@ def run_with_devices(code: str, n: int = 8) -> str:
 def test_moe_ep_shardmap_matches_local():
     run_with_devices("""
         import jax, jax.numpy as jnp, numpy as np
-        from repro.compat import make_mesh
+        from repro.launch.mesh import make_mesh
         from repro.models.common import ArchConfig
         from repro.models import moe as M
         from repro.models.layers import init_params
@@ -50,17 +50,17 @@ def test_int8_psum_cross_pod():
     run_with_devices("""
         import jax, jax.numpy as jnp, numpy as np
         from jax.sharding import PartitionSpec as P
-        from repro import compat
+        from repro.launch.mesh import make_mesh
         from repro.training.train_step import int8_psum
-        mesh = compat.make_mesh((2, 4), ("pod", "data"))
+        mesh = make_mesh((2, 4), ("pod", "data"))
         g = jnp.asarray(np.random.default_rng(0).normal(size=(2, 64)),
                         jnp.float32)
 
         def f(g):
             return int8_psum({"g": g}, "pod")["g"]
 
-        out = compat.shard_map(f, mesh=mesh, in_specs=P("pod", None),
-                               out_specs=P("pod", None), check_vma=False)(g)
+        out = jax.shard_map(f, mesh=mesh, in_specs=P("pod", None),
+                            out_specs=P("pod", None), check_vma=False)(g)
         # mean across the pod axis, with int8 quantization error bounds
         want = jnp.broadcast_to(g.mean(axis=0, keepdims=True), g.shape)
         err = np.abs(np.asarray(out) - np.asarray(want)).max()
@@ -166,7 +166,7 @@ def test_elastic_checkpoint_reshard():
 def test_train_step_cross_pod_int8_runs():
     run_with_devices("""
         import jax, jax.numpy as jnp, numpy as np
-        from repro.compat import make_mesh
+        from repro.launch.mesh import make_mesh
         from repro.configs import smoke_config
         from repro.models import model_schema
         from repro.models.layers import init_params

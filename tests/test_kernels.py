@@ -3,7 +3,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from _hypothesis_compat import given, settings, st
+from hypothesis import given, settings, strategies as st
 
 from repro.kernels import ops, ref
 
@@ -226,6 +226,23 @@ def test_scoped_topk_i8_matches_fp32_ranking():
         assert set(idf[qi].tolist()) <= set(id8[qi].tolist())
 
 
+def _list_major(n_rows, d, list_lens, block):
+    """A random store laid out list-major: list c holds ``list_lens[c]``
+    distinct row ids, padded to whole blocks (at least one) with id -1."""
+    X = RNG.normal(size=(n_rows, d)).astype(np.float32)
+    members = RNG.permutation(n_rows)
+    nblk = np.maximum(1, -(-np.asarray(list_lens) // block))
+    start = np.concatenate([[0], np.cumsum(nblk)[:-1]])
+    ids = np.full((int(nblk.sum()), block), -1, np.int32)
+    flat, lo = ids.reshape(-1), 0
+    for c, ln in enumerate(list_lens):
+        flat[start[c] * block: start[c] * block + ln] = members[lo: lo + ln]
+        lo += ln
+    rows = np.where(ids.reshape(-1, 1) >= 0, X[np.maximum(ids, 0).reshape(-1)],
+                    0.0).astype(np.float32)
+    return X, rows, ids, start.astype(np.int32), nblk.astype(np.int32)
+
+
 @pytest.mark.parametrize("b,c,d,k,metric,density", [
     (1, 128, 32, 4, "ip", 0.5),
     (4, 640, 64, 10, "ip", 0.3),
@@ -233,45 +250,50 @@ def test_scoped_topk_i8_matches_fp32_ranking():
     (8, 333, 64, 16, "l2", 0.2),
 ])
 def test_ivf_gather_topk_sweep(b, c, d, k, metric, density):
-    """Batched-IVF back half: gathered candidate tiles + explicit ids +
-    per-query packed scope words must match the unfused numpy oracle."""
-    n = 4 * c
-    X = RNG.normal(size=(n, d)).astype(np.float32)
+    """Batched-IVF list scan: each query streams its probed lists (``c``
+    rows over 6 lists of uneven length, one empty) from the list-major
+    store, masks by its packed scope words, and must match the unfused
+    numpy oracle exactly — ids and tie order included."""
+    block = 32
+    cuts = np.sort(RNG.choice(np.arange(1, c), size=4, replace=False))
+    lens = np.diff(np.concatenate([[0], cuts, [c]])).tolist() + [0]
+    n = c + 7                                   # rows in no list
+    X, rows, ids, start, nblk = _list_major(n, d, lens, block)
+    sq = np.where(ids >= 0, np.sum(X * X, axis=1)[np.maximum(ids, 0)], 0.0)
     Q = RNG.normal(size=(b, d)).astype(np.float32)
-    cand = RNG.integers(0, n, size=(b, c)).astype(np.int32)
-    cand[RNG.random((b, c)) < 0.1] = -1              # CSR padding slots
-    rows = X[np.maximum(cand, 0)]
+    probe = np.stack([RNG.permutation(len(lens))[:4] for _ in range(b)])
     dense = RNG.random((b, n)) < density
     pad = (-n) % 32
     qwords = np.stack([
         np.packbits(np.pad(m, (0, pad)), bitorder="little").view(np.uint32)
         for m in dense])
-    v1, i1 = ops.ivf_gather_topk(Q, rows, cand, qwords, k=k, metric=metric)
-    v2, i2 = ref.ivf_gather_topk_ref(Q, rows, cand, qwords, k=k,
-                                     metric=metric)
-    v1, i1 = np.asarray(v1), np.asarray(i1)
+    v1, i1 = ops.ivf_gather_topk(Q, probe, start, nblk, rows, ids, sq,
+                                 qwords, max_blocks=int(nblk.max()), k=k,
+                                 metric=metric)
+    v2, i2 = ref.ivf_gather_topk_ref(Q, probe, start, nblk, rows, ids,
+                                     qwords, k=k, metric=metric)
+    np.testing.assert_array_equal(np.asarray(i1), i2)
+    np.testing.assert_allclose(np.asarray(v1), v2, rtol=1e-5, atol=1e-5)
     for qi in range(b):
-        got = set(i1[qi][i1[qi] >= 0].tolist())
-        want = set(i2[qi][i2[qi] >= 0].tolist())
-        # duplicate candidate ids can make member sets differ on ties; the
-        # sweep draws ids with replacement, so compare scores exactly and
-        # membership modulo duplicates
-        np.testing.assert_allclose(
-            np.sort(v1[qi][i1[qi] >= 0]), np.sort(v2[qi][i2[qi] >= 0]),
-            rtol=1e-4, atol=1e-4)
-        for idx in got:
-            assert cand[qi][(cand[qi] == idx)].size and dense[qi, idx]
+        probed = set(ids[np.concatenate([
+            np.arange(start[p], start[p] + nblk[p]) for p in probe[qi]])]
+            .reshape(-1).tolist()) - {-1}
+        for idx in np.asarray(i1)[qi]:
+            assert idx < 0 or (idx in probed and dense[qi, idx])
 
 
 def test_ivf_gather_topk_all_padding_row():
-    """A query whose candidate tile is pure CSR padding yields all -1."""
+    """A query whose probed lists hold nothing in its scope yields all -1;
+    one whose scope is everything fills its k slots."""
+    lens = [0, 40, 24]
+    X, rows, ids, start, nblk = _list_major(64, 32, lens, 32)
     Q = RNG.normal(size=(2, 32)).astype(np.float32)
-    X = RNG.normal(size=(64, 32)).astype(np.float32)
-    cand = np.stack([np.full(64, -1, np.int32),
-                     np.arange(64, dtype=np.int32)])
-    rows = X[np.maximum(cand, 0)]
-    qwords = np.tile(np.full(2, 0xFFFFFFFF, np.uint32)[None, :], (2, 1))
-    v, i = ops.ivf_gather_topk(Q, rows, cand, qwords, k=4)
+    probe = np.array([[0, 1], [1, 2]])
+    qwords = np.stack([np.zeros(2, np.uint32),
+                       np.full(2, 0xFFFFFFFF, np.uint32)])
+    v, i = ops.ivf_gather_topk(Q, probe, start, nblk, rows, ids,
+                               np.zeros(ids.shape, np.float32), qwords,
+                               max_blocks=int(nblk.max()), k=4)
     assert (np.asarray(i)[0] == -1).all()
     assert (np.asarray(i)[1] >= 0).all()
 

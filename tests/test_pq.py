@@ -297,6 +297,31 @@ def test_batch_accounting_pq_terms_exclude_tombstones():
     assert "pq" not in acct3.precision_groups
 
 
+def test_pq_rescore_window_grows_with_subspaces():
+    """The default PQ window is 4k up to 16 subspaces and grows with
+    sqrt(M) beyond (16k at the 1024-d default M = 256); the database
+    applies it to dsq and dsq_batch alike, and an explicit window wins."""
+    from repro.vectordb.quant import pq_rescore_factor
+    assert [pq_rescore_factor(m) for m in (4, 16, 17, 64, 256)] == [
+        4, 4, 8, 8, 16]
+    db = DirectoryVectorDB(dim=DIM, scope_strategy="triehi",
+                           calibration=False, pq_m=DIM)     # M = 32: 8k
+    db.ingest(RNG.normal(size=(1200, DIM)).astype(np.float32),
+              ["/a/"] * 600 + ["/b/"] * 600)
+    db.build_ann("flat")
+    assert db.store.pq_m == DIM
+    q = RNG.normal(size=(4, DIM)).astype(np.float32)
+    res = db.dsq_batch(q, ["/a/", "/b/", "/", "/a/"], k=10, precision="pq")
+    assert res[0].batch.rescore_candidates == 4 * 80
+    for i, r in enumerate(res):
+        loop = db.dsq(q[i], ["/a/", "/b/", "/", "/a/"][i], k=10,
+                      precision="pq")
+        np.testing.assert_array_equal(r.ids, loop.ids)
+    acct = db.dsq_batch(q, ["/"] * 4, k=10, precision="pq",
+                        rescore_k=40)[0].batch
+    assert acct.rescore_candidates == 4 * 40
+
+
 def test_dsq_still_rejects_unknown_precision():
     db = DirectoryVectorDB(dim=DIM)
     db.ingest(RNG.normal(size=(10, DIM)).astype(np.float32), ["/a/"] * 10)

@@ -9,7 +9,7 @@ the TrieHI Eq. 1 aggregate) must hold afterwards.
 import numpy as np
 import pytest
 
-from _hypothesis_compat import given, settings, st
+from hypothesis import given, settings, strategies as st
 
 from repro.core import STRATEGIES, make_scope_index
 from repro.core import paths as P

@@ -16,6 +16,8 @@ from pathlib import Path
 import numpy as np
 import pytest
 
+from repro.vectordb.parity import topk_mismatch
+
 SRC = str(Path(__file__).resolve().parents[1] / "src")
 
 
@@ -42,9 +44,12 @@ def _mixed_db(strategy="triehi", n=600, d=16, seed=0, calibration=None):
 
 
 def _assert_parity(res_a, res_b):
+    """Sharded == flat: same ids (k-boundary ties aside) and scores within
+    a few fp32 ULP — XLA rounds the shard-local matmul shape differently
+    from the flat one, so bit-identity cannot hold across backends."""
     for a, b in zip(res_a, res_b):
-        np.testing.assert_array_equal(a.scores, b.scores)
-        np.testing.assert_array_equal(a.ids, b.ids)
+        err = topk_mismatch(b.scores, b.ids, a.scores, a.ids)
+        assert err is None, err
         assert a.scope_size == b.scope_size
 
 
@@ -64,8 +69,7 @@ def test_sharded_batch_matches_flat(strategy):
     for i in range(B):
         a = db.dsq(q[i], scopes[i], k=5, executor="flat")
         b = db.dsq(q[i], scopes[i], k=5, executor="sharded")
-        np.testing.assert_array_equal(a.scores, b.scores)
-        np.testing.assert_array_equal(a.ids, b.ids)
+        _assert_parity([a], [b])
 
 
 def test_sharded_scope_table_hits_and_accounting():
@@ -204,7 +208,7 @@ def test_sharded_serving_rag_parity():
 def test_multi_scope_input_specs_shapes():
     import jax
     import jax.numpy as jnp
-    from repro.compat import make_mesh
+    from repro.launch.mesh import make_mesh
     from repro.distributed.search import multi_scope_search_input_specs
     mesh = make_mesh((1,), ("data",))
     (db, words, alive, sids, q), shardings = multi_scope_search_input_specs(
@@ -224,7 +228,7 @@ def test_dryrun_sharded_scan_lowers():
     """The batched sharded scan lowers/compiles from specs alone (the
     launch/dryrun.py viking-scan-batch path, at toy size on 1 device)."""
     import jax
-    from repro.compat import make_mesh
+    from repro.launch.mesh import make_mesh
     from repro.distributed.search import (make_sharded_batch_search,
                                           multi_scope_search_input_specs)
     mesh = make_mesh((1,), ("data",))
