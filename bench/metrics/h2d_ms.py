@@ -1,0 +1,19 @@
+"""Mean host time per batch that the executor spends handing host arrays
+to the device: queries, candidate rows, validity masks, scan words, scope
+ids (the program's ``dsq.h2d`` spans inside the window, over the batches
+whose executor spans fall in it), ms. None where the program has no spans
+or its span ring dropped records of the window."""
+
+
+def read(run):
+    try:
+        from repro import tracing
+    except ImportError:
+        return None
+    w = run.window
+    spans = tracing.window(int(w.t0 * 1e9), int((w.t0 + w.seconds) * 1e9))
+    batches = {s.batch for s in spans.spans
+               if s.name.startswith("dsq.") and s.batch >= 0}
+    if spans.dropped or not batches:
+        return None
+    return spans.clipped_ns("dsq.h2d") / len(batches) / 1e6

@@ -19,7 +19,7 @@ from concurrent.futures import ThreadPoolExecutor
 from dataclasses import dataclass, field
 from typing import Dict, List, Optional, Sequence, Tuple
 
-from .. import faults
+from .. import faults, tracing
 from . import paths as P
 from .idset import RoaringBitmap
 from .interface import DSMStats, ResolveStats, ScopeIndex
@@ -389,24 +389,19 @@ class DSMExecutor:
 
     def apply(self, op: DSM,
               stats: Optional[DSMStats] = None) -> Optional[RoaringBitmap]:
-        t0 = time.perf_counter_ns()
-        token = self.locks.acquire(op.affected_region())
-        t1 = time.perf_counter_ns()
+        st = stats.stage_ns if stats is not None else None
+        with tracing.span("dsm.lock_wait", into=st):
+            token = self.locks.acquire(op.affected_region())
         try:
-            seq = self.journal.begin(op)
-            t2 = time.perf_counter_ns()
-            try:
-                result = op.apply(self.index, stats)
-            except Exception:
-                self.journal.abort(seq)
-                raise
-            self.journal.commit(seq)
-            if stats is not None:
-                t3 = time.perf_counter_ns()
-                st = stats.stage_ns
-                st["lock_wait"] = st.get("lock_wait", 0) + t1 - t0
-                st["journal"] = st.get("journal", 0) + t2 - t1
-                st["apply"] = st.get("apply", 0) + t3 - t2
+            with tracing.span("dsm.journal", into=st):
+                seq = self.journal.begin(op)
+            with tracing.span("dsm.apply", into=st):
+                try:
+                    result = op.apply(self.index, stats)
+                except Exception:
+                    self.journal.abort(seq)
+                    raise
+                self.journal.commit(seq)
             return result
         finally:
             self.locks.release(token)
@@ -433,12 +428,13 @@ class DSMExecutor:
         # malformed op fails the whole batch cleanly (no dangling BEGINs,
         # no stranded FIFO tickets for later acquirers to defer to)
         regions = [op.affected_region() for op in ops]
-        t0 = time.perf_counter_ns()
-        seqs = self.journal.begin_many(ops)
-        # FIFO slots reserved in submission order BEFORE any worker runs:
-        # this is what pins overlapping ops to batch order regardless of
-        # which worker thread wakes first.
-        tokens = [self.locks.enqueue(r) for r in regions]
+        st = out.stats.stage_ns
+        with tracing.span("dsm.journal", into=st):
+            seqs = self.journal.begin_many(ops)
+            # FIFO slots reserved in submission order BEFORE any worker
+            # runs: this is what pins overlapping ops to batch order
+            # regardless of which worker thread wakes first.
+            tokens = [self.locks.enqueue(r) for r in regions]
         per_op = [DSMStats() for _ in ops]     # thread-private, merged after
 
         def work(i: int) -> None:
@@ -453,28 +449,25 @@ class DSMExecutor:
             finally:
                 self.locks.release(tokens[i])
 
-        t1 = time.perf_counter_ns()
-        if max_workers <= 1 or len(ops) == 1:
-            for i in range(len(ops)):
-                work(i)
-        else:
-            # submission order == token order, so a waiting task's blockers
-            # are always already started (no pool-slot deadlock)
-            with ThreadPoolExecutor(
-                    max_workers=min(max_workers, len(ops))) as pool:
-                list(pool.map(work, range(len(ops))))
-        t2 = time.perf_counter_ns()
-        self.journal.commit_many(
-            [s for s, e in zip(seqs, out.errors) if e is None])
-        for s, e in zip(seqs, out.errors):
-            if e is not None:
-                self.journal.abort(s)
-        for ps in per_op:
-            out.stats.merge(ps)
-        st = out.stats.stage_ns
-        st["journal"] = (st.get("journal", 0) + (t1 - t0)
-                         + time.perf_counter_ns() - t2)
-        st["apply"] = st.get("apply", 0) + t2 - t1
+        with tracing.span("dsm.apply", into=st):
+            if max_workers <= 1 or len(ops) == 1:
+                for i in range(len(ops)):
+                    work(i)
+            else:
+                # submission order == token order, so a waiting task's
+                # blockers are always already started (no pool-slot
+                # deadlock)
+                with ThreadPoolExecutor(
+                        max_workers=min(max_workers, len(ops))) as pool:
+                    list(pool.map(work, range(len(ops))))
+        with tracing.span("dsm.journal", into=st):
+            self.journal.commit_many(
+                [s for s, e in zip(seqs, out.errors) if e is None])
+            for s, e in zip(seqs, out.errors):
+                if e is not None:
+                    self.journal.abort(s)
+            for ps in per_op:
+                out.stats.merge(ps)
         return out
 
     # ------------------------------------------------------------- recovery

@@ -7,9 +7,9 @@ set-difference non-recursive queries, and ancestor-membership updates on DSM.
 """
 from __future__ import annotations
 
-import time
 from typing import Dict, List, Optional, Tuple
 
+from .. import tracing
 from . import paths as P
 from .auxdir import AuxDirectoryIndex
 from .catalog import PathRef
@@ -97,42 +97,36 @@ class PEOfflineIndex(ScopeIndex):
     def resolve(self, path: P.Path | str, recursive: bool = True,
                 stats: Optional[ResolveStats] = None) -> RoaringBitmap:
         path = P.parse(path)
+        st = stats.stage_ns if stats is not None else None
         if recursive:
-            t0 = time.perf_counter_ns()
-            with self._agg_latch:    # vs in-place posting writes
-                posting = self.postings.get(path)
-                out = posting.copy() if posting is not None else RoaringBitmap()
+            with tracing.span("resolve.bitmap_fetch", into=st):
+                with self._agg_latch:    # vs in-place posting writes
+                    posting = self.postings.get(path)
+                    out = (posting.copy() if posting is not None
+                           else RoaringBitmap())
             if stats is not None:
                 stats.posting_fetches += 1
-                stats.stage_ns["bitmap_fetch"] = (
-                    stats.stage_ns.get("bitmap_fetch", 0)
-                    + time.perf_counter_ns() - t0)
             return out
         # non-recursive: Set_total \ union(direct child subtree postings)
-        t0 = time.perf_counter_ns()
-        total = self.postings.get(path)
-        if total is None:
-            return RoaringBitmap()
-        # a snapshot: DSM workers edit the live child set concurrently
-        child_names = tuple(self.aux.children(path))
-        t1 = time.perf_counter_ns()
+        with tracing.span("resolve.bitmap_fetch", into=st):
+            total = self.postings.get(path)
+            if total is None:
+                return RoaringBitmap()
+            # a snapshot: DSM workers edit the live child set concurrently
+            child_names = tuple(self.aux.children(path))
         children = RoaringBitmap()
         fetches = 1
-        with self._agg_latch:
-            for name in child_names:
-                cp = self.postings.get(path + (name,))
-                if cp is not None:
-                    children |= cp
-                    fetches += 1
-            out = total - children
-        t2 = time.perf_counter_ns()
+        with tracing.span("resolve.bitmap_compute", into=st):
+            with self._agg_latch:
+                for name in child_names:
+                    cp = self.postings.get(path + (name,))
+                    if cp is not None:
+                        children |= cp
+                        fetches += 1
+                out = total - children
         if stats is not None:
             stats.posting_fetches += fetches
             stats.set_ops += len(child_names) + 1
-            stats.stage_ns["bitmap_fetch"] = (
-                stats.stage_ns.get("bitmap_fetch", 0) + t1 - t0)
-            stats.stage_ns["bitmap_compute"] = (
-                stats.stage_ns.get("bitmap_compute", 0) + t2 - t1)
         return out
 
     # ------------------------------------------------------------------ DSM

@@ -6,9 +6,9 @@ posting lists at query time. DSM remaps path keys at the directory-key level.
 """
 from __future__ import annotations
 
-import time
 from typing import Dict, List, Optional
 
+from .. import tracing
 from . import paths as P
 from .auxdir import AuxDirectoryIndex
 from .catalog import PathRef
@@ -82,38 +82,32 @@ class PEOnlineIndex(ScopeIndex):
     def resolve(self, path: P.Path | str, recursive: bool = True,
                 stats: Optional[ResolveStats] = None) -> RoaringBitmap:
         path = P.parse(path)
+        st = stats.stage_ns if stats is not None else None
         if not recursive:
-            t0 = time.perf_counter_ns()
-            with self._agg_latch:    # vs in-place posting writes
-                posting = self.postings.get(path)
-                out = posting.copy() if posting is not None else RoaringBitmap()
+            with tracing.span("resolve.bitmap_fetch", into=st):
+                with self._agg_latch:    # vs in-place posting writes
+                    posting = self.postings.get(path)
+                    out = (posting.copy() if posting is not None
+                           else RoaringBitmap())
             if stats is not None:
                 stats.posting_fetches += 1
-                stats.stage_ns["bitmap_fetch"] = (
-                    stats.stage_ns.get("bitmap_fetch", 0)
-                    + time.perf_counter_ns() - t0)
             return out
         # recursive: enumerate subtree keys (m_q), fetch postings, union
-        t0 = time.perf_counter_ns()
-        keys = self.aux.subtree_keys(path)
-        t1 = time.perf_counter_ns()
+        with tracing.span("resolve.subpath_obtain", into=st):
+            keys = self.aux.subtree_keys(path)
         out = RoaringBitmap()
         fetches = 0
-        with self._agg_latch:
-            for k in keys:
-                posting = self.postings.get(k)
-                if posting is not None:
-                    out |= posting
-                    fetches += 1
-        t2 = time.perf_counter_ns()
+        with tracing.span("resolve.bitmap_fetch", into=st):
+            with self._agg_latch:
+                for k in keys:
+                    posting = self.postings.get(k)
+                    if posting is not None:
+                        out |= posting
+                        fetches += 1
         if stats is not None:
             stats.subpath_keys += len(keys)
             stats.posting_fetches += fetches
             stats.set_ops += fetches
-            stats.stage_ns["subpath_obtain"] = (
-                stats.stage_ns.get("subpath_obtain", 0) + t1 - t0)
-            stats.stage_ns["bitmap_fetch"] = (
-                stats.stage_ns.get("bitmap_fetch", 0) + t2 - t1)
         return out
 
     # ------------------------------------------------------------------ DSM

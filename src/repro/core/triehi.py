@@ -16,9 +16,9 @@ entry->node catalog resolution stays O(α) without per-entry rewrites.
 """
 from __future__ import annotations
 
-import time
 from typing import Dict, Iterator, List, Optional, Tuple
 
+from .. import tracing
 from . import paths as P
 from .idset import RoaringBitmap
 from .interface import DSMDelta, DSMStats, ResolveStats, ScopeIndex
@@ -154,35 +154,29 @@ class TrieHIIndex(ScopeIndex):
     # ----------------------------------------------------------------- read
     def resolve(self, path: P.Path | str, recursive: bool = True,
                 stats: Optional[ResolveStats] = None) -> RoaringBitmap:
-        t0 = time.perf_counter_ns()
-        node = self._walk(P.parse(path), create=False, stats=stats)
-        t1 = time.perf_counter_ns()
-        if stats is not None:
-            stats.stage_ns["traverse"] = stats.stage_ns.get("traverse", 0) + t1 - t0
+        st = stats.stage_ns if stats is not None else None
+        with tracing.span("resolve.traverse", into=st):
+            node = self._walk(P.parse(path), create=False, stats=stats)
         if node is None:
             return RoaringBitmap()
         if recursive:
-            with self._agg_latch:    # vs in-place DSM/ingest container writes
-                out = node.inclusive.copy()
-            t2 = time.perf_counter_ns()
+            with tracing.span("resolve.bitmap_fetch", into=st):
+                with self._agg_latch:    # vs in-place DSM/ingest writes
+                    out = node.inclusive.copy()
             if stats is not None:
                 stats.posting_fetches += 1
-                stats.stage_ns["bitmap_fetch"] = (
-                    stats.stage_ns.get("bitmap_fetch", 0) + t2 - t1)
             return out
         # non-recursive: Inc(p) \ union(Inc(children)) (paper-faithful; equals
         # Local(p) by Eq. 1 — asserted in check_invariants)
-        with self._agg_latch:
-            children = RoaringBitmap()
-            for child in node.children.values():
-                children |= child.inclusive
-            out = node.inclusive - children
-        t2 = time.perf_counter_ns()
+        with tracing.span("resolve.bitmap_compute", into=st):
+            with self._agg_latch:
+                children = RoaringBitmap()
+                for child in node.children.values():
+                    children |= child.inclusive
+                out = node.inclusive - children
         if stats is not None:
             stats.posting_fetches += 1 + len(node.children)
             stats.set_ops += len(node.children) + 1
-            stats.stage_ns["bitmap_compute"] = (
-                stats.stage_ns.get("bitmap_compute", 0) + t2 - t1)
         return out
 
     def scope_token(self, path: P.Path | str, recursive: bool = True):

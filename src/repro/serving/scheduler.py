@@ -49,7 +49,7 @@ from typing import Callable, Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
-from .. import faults
+from .. import faults, tracing
 from ..core.interface import normalize_batch
 from ..vectordb.planner import BatchAccounting, ScopeKey
 
@@ -627,23 +627,26 @@ class ContinuousScheduler:
         return picked
 
     # ------------------------------------------------------- stage + execute
-    def _do_stage(self, batch: List[_Request]) -> Tuple[object, float]:
+    def _do_stage(self, batch: List[_Request], seq: Optional[int] = None
+                  ) -> Tuple[object, float]:
+        """Stage ``batch`` (batch id ``seq``, fresh when None); returns the
+        staged value and the seconds staging took."""
         if self.stage_fn is None:
             return None, 0.0
-        t0 = self.clock()
-        try:
-            faults.fire("sched.stage")
-            staged = self.stage_fn([r.payload for r in batch])
-        except Exception:                # noqa: BLE001 — staging only warms
-            # token-validated caches: a failed stage costs performance, not
-            # correctness. Execute unstaged rather than killing the batch
-            # (or, threaded, the collector thread).
-            self.stage_faults += 1
-            return None, self.clock() - t0
-        return staged, self.clock() - t0
+        staged = None
+        with tracing.batch(seq), tracing.span("sched.stage") as sp:
+            try:
+                faults.fire("sched.stage")
+                staged = self.stage_fn([r.payload for r in batch])
+            except Exception:            # noqa: BLE001 — staging only warms
+                # token-validated caches: a failed stage costs performance,
+                # not correctness. Execute unstaged rather than killing the
+                # batch (or, threaded, the collector thread).
+                self.stage_faults += 1
+        return staged, sp.ns / 1e9
 
     def _run_batch(self, batch: List[_Request], staged, stage_s: float,
-                   flush: str) -> None:
+                   flush: str, seq: Optional[int] = None) -> None:
         t0 = self.clock()
         try:
             # Seam: "latency" = injected kernel slowness, "error" = executor
@@ -651,7 +654,9 @@ class ContinuousScheduler:
             # breaker), "crash" = thread death (InjectedCrash is a
             # BaseException, so it escapes this handler by design).
             faults.fire("sched.execute")
-            results = self.execute_fn([r.payload for r in batch], staged)
+            with tracing.batch(seq):
+                results = self.execute_fn([r.payload for r in batch],
+                                          staged)
             if len(results) != len(batch):
                 raise RuntimeError(f"execute returned {len(results)} results "
                                    f"for {len(batch)} requests")
@@ -719,8 +724,9 @@ class ContinuousScheduler:
         if not batch:
             self._maybe_maintain(force=True)
             return 0
-        staged, stage_s = self._do_stage(batch)
-        self._run_batch(batch, staged, stage_s, "pump")
+        seq = tracing.new_batch()
+        staged, stage_s = self._do_stage(batch, seq)
+        self._run_batch(batch, staged, stage_s, "pump", seq)
         self._since_maintenance += 1
         self._maybe_maintain()
         return len(batch)
@@ -743,7 +749,9 @@ class ContinuousScheduler:
         self._since_maintenance = 0
         t0 = self.clock()
         try:
-            if self.maintenance_fn() is not None:
+            with tracing.span("sched.maint"):
+                done = self.maintenance_fn()
+            if done is not None:
                 self.maintenance_steps += 1
                 dt = self.clock() - t0
                 self._maint_cost_ewma_s = (dt if not self._maint_cost_ewma_s
@@ -802,15 +810,17 @@ class ContinuousScheduler:
             if batch:
                 self._collecting = batch  # for fail-fast resolution on death
                 faults.fire("sched.collect")
-                staged, stage_s = self._do_stage(batch)
+                seq = tracing.new_batch()
+                staged, stage_s = self._do_stage(batch, seq)
                 # blocks while one batch is already staged and one executes:
                 # exactly one batch of lookahead — the double buffer. The
                 # put is health-aware: an executor that died mid-wait would
                 # otherwise leave us blocked on a queue nobody drains.
                 while True:
                     try:
-                        self._staged.put((batch, staged, stage_s, flush),
-                                         timeout=0.05)
+                        self._staged.put(
+                            (batch, staged, stage_s, flush, seq),
+                            timeout=0.05)
                         self._collecting = None
                         break
                     except queue.Full:

@@ -15,6 +15,7 @@ from typing import Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
+from .. import tracing
 from ..core import (DSM, DSMBatchResult, DSMExecutor, DSMJournal, DSMStats,
                     ResolveStats, ScopeIndex, make_scope_index)
 from ..core.interface import normalize_batch
@@ -199,14 +200,14 @@ class DirectoryVectorDB:
                                          self._rescore_floor(precision))
         idx = self.namespaces[namespace]
         stats = ResolveStats()
-        t0 = time.perf_counter_ns()
-        if exclude:
-            scope = idx.resolve_exclusion(path, list(exclude),
-                                          recursive=recursive, stats=stats)
-        else:
-            scope = idx.resolve(path, recursive=recursive, stats=stats)
-        candidate_ids = scope.to_array()
-        t1 = time.perf_counter_ns()
+        with tracing.span("dsq.plan") as plan:
+            if exclude:
+                scope = idx.resolve_exclusion(path, list(exclude),
+                                              recursive=recursive,
+                                              stats=stats)
+            else:
+                scope = idx.resolve(path, recursive=recursive, stats=stats)
+            candidate_ids = scope.to_array()
         ex = self.executors.get(executor)
         if ex is None:
             raise ValueError(f"executor {executor!r} not built "
@@ -214,9 +215,9 @@ class DirectoryVectorDB:
         scores, ids = ex.search(queries, k, candidate_ids=candidate_ids,
                                 precision=precision, rescore_k=rescore_k,
                                 **executor_params)
-        t2 = time.perf_counter_ns()
         return DSQResult(ids=ids, scores=scores, scope_size=len(candidate_ids),
-                         directory_ns=t1 - t0, ann_ns=t2 - t1,
+                         directory_ns=plan.ns,
+                         ann_ns=time.perf_counter_ns() - plan.end_ns,
                          resolve_stats=stats)
 
     def planner(self, namespace: str = DEFAULT_NS) -> BatchPlanner:
@@ -394,26 +395,29 @@ class DirectoryVectorDB:
         for ivf, ef-widened for pg)."""
         B = queries.shape[0]
         idx = self.namespaces[namespace]
-        acct = BatchAccounting()
-        t0 = time.perf_counter_ns()
-        specs = normalize_batch(paths, recursive, exclude)
-        groups = self.planner(namespace).plan(
-            idx, len(self.store), specs, k, acct, precision=precision,
-            rescore_k=rescore_k)
-        t1 = time.perf_counter_ns()
-        acct.directory_ns = t1 - t0
-        model = model_of(self.store)
-        acct.plan_source = model.source
-        acct.predicted_ann_ns = model.estimate_batch_ns(
-            [(g.plan, g.precision, g.scope_size, len(g.request_idx))
-             for g in groups],
-            n=len(self.store), k=k, rescore_k=rescore_k, dim=self.store.dim)
-        out_scores = np.full((B, k), -np.inf, np.float32)
-        out_ids = np.full((B, k), -1, np.int64)
-        fetch0 = self.store.rescore_fetch_bytes
-        retries0 = self.store.host_fetch_retries
-        launch(groups, out_scores, out_ids, acct)
-        acct.ann_ns = time.perf_counter_ns() - t1
+        with tracing.batch() as cur:
+            acct = BatchAccounting(seq=cur.seq)
+            with tracing.span("dsq.plan") as plan:
+                specs = normalize_batch(paths, recursive, exclude)
+                groups = self.planner(namespace).plan(
+                    idx, len(self.store), specs, k, acct,
+                    precision=precision, rescore_k=rescore_k)
+            acct.directory_ns = plan.ns
+            model = model_of(self.store)
+            acct.plan_source = model.source
+            acct.predicted_ann_ns = model.estimate_batch_ns(
+                [(g.plan, g.precision, g.scope_size, len(g.request_idx))
+                 for g in groups],
+                n=len(self.store), k=k, rescore_k=rescore_k,
+                dim=self.store.dim)
+            out_scores = np.full((B, k), -np.inf, np.float32)
+            out_ids = np.full((B, k), -1, np.int64)
+            fetch0 = self.store.rescore_fetch_bytes
+            retries0 = self.store.host_fetch_retries
+            h2d0 = cur.h2d_bytes
+            launch(groups, out_scores, out_ids, acct)
+            acct.ann_ns = time.perf_counter_ns() - plan.end_ns
+            acct.h2d_bytes = cur.h2d_bytes - h2d0
         # resident-store byte terms are *alive-row* bytes: tombstoned rows
         # still occupy buffer slots but are not part of the serving corpus
         if any(g.precision == "int8" for g in groups):
@@ -658,12 +662,11 @@ class DirectoryVectorDB:
         idx = self.namespaces[namespace]
         ex = self.executors[executor]
         acct = BatchAccounting()
-        t0 = time.perf_counter_ns()
-        specs = normalize_batch(paths, recursive, exclude)
-        scopes = idx.resolve_batch(paths, recursive, exclude,
-                                   stats=acct.resolve_stats)
+        with tracing.span("dsq.plan") as plan:
+            specs = normalize_batch(paths, recursive, exclude)
+            scopes = idx.resolve_batch(paths, recursive, exclude,
+                                       stats=acct.resolve_stats)
         cand: Dict[int, np.ndarray] = {}      # id(bitmap) -> shared id array
-        t1 = time.perf_counter_ns()
         out = []
         for i, scope in enumerate(scopes):
             ids_arr = cand.get(id(scope))
@@ -674,13 +677,12 @@ class DirectoryVectorDB:
                                     **executor_params)
             out.append(DSQResult(
                 ids=ids, scores=scores, scope_size=len(ids_arr),
-                directory_ns=(t1 - t0) // max(len(specs), 1), ann_ns=0,
+                directory_ns=plan.ns // max(len(specs), 1), ann_ns=0,
                 resolve_stats=acct.resolve_stats, batch=acct))
-        t2 = time.perf_counter_ns()
         acct.batch_size = len(specs)
         acct.unique_scopes = len(cand)
-        acct.directory_ns = t1 - t0
-        acct.ann_ns = t2 - t1
+        acct.directory_ns = plan.ns
+        acct.ann_ns = time.perf_counter_ns() - plan.end_ns
         acct.launches = len(specs)
         ann_share = acct.ann_ns // max(len(specs), 1)
         for r in out:

@@ -26,6 +26,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
+from .. import tracing
 # the hand-set crossover now lives in costmodel (re-exported here because
 # this module owns the decision *rule* that consumes it)
 from .costmodel import GATHER_THRESHOLD, model_of
@@ -57,6 +58,21 @@ def pad_rows(x, n: int, value=0) -> np.ndarray:
     x = np.asarray(x)
     pad = [(0, n - x.shape[0])] + [(0, 0)] * (x.ndim - 1)
     return np.pad(x, pad, constant_values=value)
+
+
+def to_device(*arrays) -> Tuple[jnp.ndarray, ...]:
+    """Host arrays handed to the device, in one ``dsq.h2d`` span whose bytes
+    count toward the batch's ``h2d_bytes``."""
+    with tracing.span("dsq.h2d"):
+        tracing.count_h2d(sum(a.nbytes for a in arrays))
+        return tuple(jnp.asarray(a) for a in arrays)
+
+
+def to_host(*arrays) -> Tuple[np.ndarray, ...]:
+    """Device results on the host, in one ``dsq.fetch`` span: the time the
+    thread waits for the device."""
+    with tracing.span("dsq.fetch"):
+        return tuple(np.asarray(a) for a in arrays)
 
 
 def choose_plan(m: int, n: int, k: int,
@@ -272,20 +288,19 @@ def gather_rescore(store: VectorStore, queries: np.ndarray,
         pm = store.pinned_mask()
         if pm is not None:
             fetch = fetch & ~pm[np.maximum(cand_ids, 0)]
-        n_fetch = int(np.count_nonzero(fetch))
-        store.rescore_fetch_rows += n_fetch
-        store.rescore_fetch_bytes += n_fetch * store.dim * 4
-    rows = store.fetch_rows(np.maximum(cand_ids, 0))         # (B, R, d)
+        store.rescore_fetch_bytes += (int(np.count_nonzero(fetch))
+                                      * store.dim * 4)
     kk = min(k, cand_ids.shape[1])
     b, bp = len(queries), bucket(len(queries))
-    vals, loc = _rescore_topk(jnp.asarray(pad_rows(queries, bp)),
-                              jnp.asarray(pad_rows(rows, bp)),
-                              jnp.asarray(pad_rows(cand_ids >= 0, bp)), kk,
-                              store.metric)
-    vals = np.asarray(vals, dtype=np.float32)[:b]
-    loc = np.asarray(loc)[:b]
-    ids = np.take_along_axis(cand_ids, np.asarray(loc, dtype=np.int64),
-                             axis=1)
+    with tracing.span("dsq.gather.rows"):
+        rows = pad_rows(store.fetch_rows(np.maximum(cand_ids, 0)), bp)
+    q, rows, valid = to_device(pad_rows(queries, bp), rows,
+                               pad_rows(cand_ids >= 0, bp))
+    with tracing.span("dsq.launch"):
+        vals, loc = _rescore_topk(q, rows, valid, kk, store.metric)
+    vals, loc = to_host(vals, loc)
+    vals = np.asarray(vals[:b], np.float32)
+    ids = np.take_along_axis(cand_ids, loc[:b].astype(np.int64), axis=1)
     ids[~np.isfinite(vals)] = -1
     return pad_topk(vals, ids, k)
 
@@ -356,21 +371,26 @@ class FlatExecutor:
                 return self._search_pq(queries, k, candidate_ids, plan, r)
         kk = min(k, m)
         b = len(queries)
-        qp = jnp.asarray(pad_rows(queries, bucket(b)))
+        qp = pad_rows(queries, bucket(b))
         if plan == "gather":
             mp = bucket(m)
-            scores, local = _gather_topk(
-                qp, jnp.asarray(pad_rows(self.store.vectors[candidate_ids],
-                                         mp)),
-                jnp.asarray(np.arange(mp) < m), kk, self.store.metric)
-            ids = candidate_ids[np.asarray(local)[:b]]
+            with tracing.span("dsq.gather.rows"):
+                rows = pad_rows(self.store.vectors[candidate_ids], mp)
+            qp, rows, valid = to_device(qp, rows, np.arange(mp) < m)
+            with tracing.span("dsq.launch"):
+                scores, local = _gather_topk(qp, rows, valid, kk,
+                                             self.store.metric)
+            scores, local = to_host(scores, local)
+            ids = candidate_ids[local[:b]]
         else:
-            words = pack_ids_to_words(candidate_ids, n)
-            scores, ids = _scan_topk(
-                qp, self.store.device_vectors(), self._sq(),
-                jnp.asarray(words), kk, self.store.metric)
-            ids = np.asarray(ids)[:b]
-        return pad_topk(np.asarray(scores)[:b], ids, k)
+            qp, words = to_device(qp, pack_ids_to_words(candidate_ids, n))
+            with tracing.span("dsq.launch"):
+                scores, ids = _scan_topk(
+                    qp, self.store.device_vectors(), self._sq(), words, kk,
+                    self.store.metric)
+            scores, ids = to_host(scores, ids)
+            ids = ids[:b]
+        return pad_topk(scores[:b], ids, k)
 
     def _search_int8(self, queries: np.ndarray, k: int,
                      candidate_ids: np.ndarray, plan: str, r: int
@@ -381,29 +401,32 @@ class FlatExecutor:
         if plan == "gather":
             m = len(candidate_ids)
             mp = bucket(m)
-            cand_sq = (pad_rows(self.store.q_sq_norms()[candidate_ids], mp)
-                       if self.store.metric == "l2"
-                       else np.zeros(0, np.float32))
-            _, local = _gather_topk_i8(
-                jnp.asarray(q_i8), jnp.asarray(q_s),
-                jnp.asarray(pad_rows(self.store.q_vectors[candidate_ids],
-                                     mp)),
-                jnp.asarray(pad_rows(self.store.q_scales[candidate_ids], mp)),
-                jnp.asarray(cand_sq), jnp.asarray(np.arange(mp) < m), r,
-                self.store.metric)
-            cand = np.asarray(candidate_ids, np.int64)[
-                np.asarray(local)[:b]]
+            with tracing.span("dsq.gather.rows"):
+                cand_sq = (pad_rows(self.store.q_sq_norms()[candidate_ids],
+                                    mp)
+                           if self.store.metric == "l2"
+                           else np.zeros(0, np.float32))
+                rows = pad_rows(self.store.q_vectors[candidate_ids], mp)
+                scales = pad_rows(self.store.q_scales[candidate_ids], mp)
+            args = to_device(q_i8, q_s, rows, scales, cand_sq,
+                             np.arange(mp) < m)
+            with tracing.span("dsq.launch"):
+                _, local = _gather_topk_i8(*args, r, self.store.metric)
+            local, = to_host(local)
+            cand = np.asarray(candidate_ids, np.int64)[local[:b]]
         else:
-            words = pack_ids_to_words(candidate_ids, n)
-            vals, cand = _scan_topk_i8(
-                jnp.asarray(q_i8), jnp.asarray(q_s),
-                self.store.device_q_vectors(), self.store.device_q_scales(),
-                self._q_sq(), jnp.asarray(words), min(r, n),
-                self.store.metric)
-            cand = np.asarray(cand, dtype=np.int64)[:b]
+            q_i8, q_s, words = to_device(
+                q_i8, q_s, pack_ids_to_words(candidate_ids, n))
+            with tracing.span("dsq.launch"):
+                vals, cand = _scan_topk_i8(
+                    q_i8, q_s, self.store.device_q_vectors(),
+                    self.store.device_q_scales(), self._q_sq(), words,
+                    min(r, n), self.store.metric)
+            vals, cand = to_host(vals, cand)
+            cand = cand[:b].astype(np.int64)
             # top_k hands exhausted (-inf) lanes arbitrary column ids — they
             # are out-of-scope rows and must not reach the rescore
-            cand[~np.isfinite(np.asarray(vals)[:b])] = -1
+            cand[~np.isfinite(vals[:b])] = -1
         return gather_rescore(self.store, queries, cand, k)
 
     def _search_pq(self, queries: np.ndarray, k: int,
@@ -412,24 +435,27 @@ class FlatExecutor:
         """Two-phase PQ path of :meth:`search`: ADC scan/gather over the
         uint8 codes selects ``r`` candidates, exact fp32 rescore ranks k."""
         n, b = len(self.store), len(queries)
-        lut = jnp.asarray(self.store.pq_lut(pad_rows(queries, bucket(b))))
+        lut = self.store.pq_lut(pad_rows(queries, bucket(b)))
         if plan == "gather":
             m = len(candidate_ids)
             mp = bucket(m)
-            _, local = _gather_topk_pq(
-                lut, jnp.asarray(pad_rows(self.store.pq_codes[candidate_ids],
-                                          mp)),
-                jnp.asarray(np.arange(mp) < m), r)
-            cand = np.asarray(candidate_ids, np.int64)[
-                np.asarray(local)[:b]]
+            with tracing.span("dsq.gather.rows"):
+                codes = pad_rows(self.store.pq_codes[candidate_ids], mp)
+            args = to_device(lut, codes, np.arange(mp) < m)
+            with tracing.span("dsq.launch"):
+                _, local = _gather_topk_pq(*args, r)
+            local, = to_host(local)
+            cand = np.asarray(candidate_ids, np.int64)[local[:b]]
         else:
-            words = pack_ids_to_words(candidate_ids, n)
-            vals, cand = _scan_topk_pq(lut, self.store.device_pq_codes(),
-                                       jnp.asarray(words), min(r, n))
-            cand = np.asarray(cand, dtype=np.int64)[:b]
+            lut, words = to_device(lut, pack_ids_to_words(candidate_ids, n))
+            with tracing.span("dsq.launch"):
+                vals, cand = _scan_topk_pq(lut, self.store.device_pq_codes(),
+                                           words, min(r, n))
+            vals, cand = to_host(vals, cand)
+            cand = cand[:b].astype(np.int64)
             # exhausted (-inf) lanes carry arbitrary top_k column ids — out
             # of scope, keep them away from the rescore
-            cand[~np.isfinite(np.asarray(vals)[:b])] = -1
+            cand[~np.isfinite(vals[:b])] = -1
         return gather_rescore(self.store, queries, cand, k)
 
     def search_multi(self, queries: np.ndarray, mask_words: np.ndarray,
@@ -453,58 +479,64 @@ class FlatExecutor:
         queries = np.atleast_2d(np.asarray(queries, dtype=np.float32))
         b = len(queries)
         qp = pad_rows(queries, bucket(b))
-        words = jnp.asarray(pad_rows(np.asarray(mask_words, np.uint32),
-                                     bucket(len(mask_words))))
-        sids = jnp.asarray(pad_rows(np.asarray(scope_ids, np.int32),
-                                    bucket(b)))
+        words = pad_rows(np.asarray(mask_words, np.uint32),
+                         bucket(len(mask_words)))
+        sids = pad_rows(np.asarray(scope_ids, np.int32), bucket(b))
         if precision in ("int8", "pq"):
             r = resolve_rescore_k(k, rescore_k, len(self.store))
             phase1 = (self._scan_multi_int8 if precision == "int8"
                       else self._scan_multi_pq)
             vals, cand = phase1(qp, words, sids, r, use_pallas)
-            cand = np.asarray(cand, dtype=np.int64)[:b]
+            vals, cand = to_host(vals, cand)
+            cand = cand[:b].astype(np.int64)
             # exhausted (-inf) lanes carry arbitrary top_k column ids (the
             # fused kernels already yield -1); mask them out of the rescore
-            cand[~np.isfinite(np.asarray(vals)[:b])] = -1
+            cand[~np.isfinite(vals[:b])] = -1
             return gather_rescore(self.store, queries, cand, k)
-        if use_pallas:
-            scores, ids = kops.multi_scope_topk(
-                qp, self.store.device_vectors(), words, sids, k=k,
-                metric=self.store.metric, sq=self._sq())
-        else:
-            scores, ids = _multi_scan_topk(
-                jnp.asarray(qp), self.store.device_vectors(), self._sq(),
-                words, sids, k, self.store.metric)
-        scores = np.asarray(scores)[:b]
-        ids = np.asarray(ids, dtype=np.int64)[:b]
+        qp, words, sids = to_device(qp, words, sids)
+        with tracing.span("dsq.launch"):
+            if use_pallas:
+                scores, ids = kops.multi_scope_topk(
+                    qp, self.store.device_vectors(), words, sids, k=k,
+                    metric=self.store.metric, sq=self._sq())
+            else:
+                scores, ids = _multi_scan_topk(
+                    qp, self.store.device_vectors(), self._sq(), words, sids,
+                    k, self.store.metric)
+        scores, ids = to_host(scores, ids)
+        scores = scores[:b]
+        ids = ids[:b].astype(np.int64)
         ids[~np.isfinite(scores)] = -1
         return scores, ids
 
     def _scan_multi_int8(self, queries, words, sids, r, use_pallas):
         """int8 scan phase of :meth:`search_multi`: (vals, cand) (B, r)."""
         from ..kernels import ops as kops
-        q_i8, q_s = quantize_rows(queries)
-        if use_pallas:
-            # the kernel streams the sq tile unconditionally; hand it a
-            # device zeros vector on the metrics that never read it
-            sq = (self.store.device_q_sq_norms()
-                  if self.store.metric == "l2"
-                  else jnp.zeros(len(self.store), jnp.float32))
-            return kops.multi_scope_topk_i8(
+        q_i8, q_s, words, sids = to_device(*quantize_rows(queries), words,
+                                           sids)
+        with tracing.span("dsq.launch"):
+            if use_pallas:
+                # the kernel streams the sq tile unconditionally; hand it a
+                # device zeros vector on the metrics that never read it
+                sq = (self.store.device_q_sq_norms()
+                      if self.store.metric == "l2"
+                      else jnp.zeros(len(self.store), jnp.float32))
+                return kops.multi_scope_topk_i8(
+                    q_i8, q_s, self.store.device_q_vectors(),
+                    self.store.device_q_scales(), sq, words, sids, k=r,
+                    metric=self.store.metric)
+            return _multi_scan_topk_i8(
                 q_i8, q_s, self.store.device_q_vectors(),
-                self.store.device_q_scales(), sq, words, sids, k=r,
-                metric=self.store.metric)
-        return _multi_scan_topk_i8(
-            jnp.asarray(q_i8), jnp.asarray(q_s),
-            self.store.device_q_vectors(), self.store.device_q_scales(),
-            self._q_sq(), words, sids, r, self.store.metric)
+                self.store.device_q_scales(), self._q_sq(), words, sids, r,
+                self.store.metric)
 
     def _scan_multi_pq(self, queries, words, sids, r, use_pallas):
         """PQ/ADC scan phase of :meth:`search_multi`: (vals, cand) (B, r)."""
         from ..kernels import ops as kops
-        lut = self.store.pq_lut(queries)
-        if use_pallas:
-            return kops.multi_scope_topk_pq(
-                lut, self.store.device_pq_codes(), words, sids, k=r)
-        return _multi_scan_topk_pq(
-            jnp.asarray(lut), self.store.device_pq_codes(), words, sids, r)
+        lut, words, sids = to_device(self.store.pq_lut(queries), words, sids)
+        with tracing.span("dsq.launch"):
+            if use_pallas:
+                return kops.multi_scope_topk_pq(
+                    lut, self.store.device_pq_codes(), words, sids, k=r)
+            return _multi_scan_topk_pq(lut, self.store.device_pq_codes(),
+                                       words, sids, r)

@@ -29,6 +29,7 @@ from typing import Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
+from .. import tracing
 from ..core import ResolveStats, RoaringBitmap, ScopeIndex
 from ..core import paths as P
 from ..core.interface import DSMDelta, ScopeSpec
@@ -177,7 +178,7 @@ class ScopeMaskCache:
         added = {id(n): (old, new) for n, old, new in event.added_to}
         if not removed and not added:
             return {"patched": 0, "evicted": 0}
-        with self._lock:
+        with self._lock, tracing.span("dsm.cache_patch"):
             patch: List[Tuple[ScopeKey, CachedScope, int, int]] = []
             evict: List[ScopeKey] = []
             for key, ent in self._entries.items():
@@ -295,6 +296,7 @@ class PlanGroup:
 class BatchAccounting:
     """Shared-resolution accounting for one dsq_batch call: attached to every
     per-request DSQResult so callers can see how much work was amortized."""
+    seq: int = 0                     # batch id every span of the batch carries
     batch_size: int = 0
     unique_scopes: int = 0
     scope_cache_hits: int = 0
@@ -302,6 +304,7 @@ class BatchAccounting:
     plan_groups: Dict[str, int] = field(default_factory=dict)
     directory_ns: int = 0            # total resolve+plan time, whole batch
     ann_ns: int = 0                  # total ranking time, whole batch
+    h2d_bytes: int = 0               # host bytes handed to the device
     resolve_stats: ResolveStats = field(default_factory=ResolveStats)
     # sharded-executor terms (zero on single-device paths): what this batch
     # actually moved between host and mesh, and across the mesh
@@ -351,8 +354,9 @@ class BatchAccounting:
         aggregation the serving layer uses (one cumulative ``BatchAccounting``
         per window instead of re-creating the server to reset counters).
         Counters sum; dict terms sum per key; byte/placement gauges take the
-        latest observation; ``tiered`` is sticky within the window."""
-        gauges = {"db_bytes_fp32", "db_bytes_int8", "db_bytes_pq",
+        latest observation, as does the batch id; ``tiered`` is sticky
+        within the window."""
+        gauges = {"seq", "db_bytes_fp32", "db_bytes_int8", "db_bytes_pq",
                   "rows_device_pinned", "rows_host", "n_shards"}
         for f in dataclasses.fields(self):
             ov = getattr(other, f.name)

@@ -124,12 +124,11 @@ class VectorStore:
         # fp32 rows outgrow it, fp32 rows demote to host RAM — only the PQ
         # codes (plus any hot-pinned fp32 rows) stay device-resident, and
         # the exact rows are fetched per batch for the gather_rescore
-        # window. The fetch counters are cumulative; per-batch accounting
+        # window. The fetch byte counter is cumulative; per-batch accounting
         # snapshots the delta.
         self._device_budget: Optional[int] = None
         self._pinned: Optional[np.ndarray] = None
         self.rescore_fetch_bytes = 0
-        self.rescore_fetch_rows = 0
         # Host-fetch fault handling: transient faults at the
         # ``store.host_fetch`` seam are retried with exponential backoff
         # (bounded), counted here and surfaced through BatchAccounting.
