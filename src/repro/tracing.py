@@ -17,9 +17,10 @@ per-directory loop.
 A batch of the serving path has one id, from :func:`new_batch`, which every
 span of the batch carries and which ``BatchAccounting.seq`` repeats. The
 scheduler opens :func:`batch` around the work of each batch on each of its
-threads; spans opened there take its id, and :func:`count_h2d` adds to its
-host-to-device byte count. Names never start with ``bench.``: that prefix
-belongs to the benchmark's own spans.
+threads; spans opened there take its id, :func:`count_h2d` adds to its
+host-to-device byte count and :func:`count_gather_rows` to its gathered
+rows. Names never start with ``bench.``: that prefix belongs to the
+benchmark's own spans.
 """
 from __future__ import annotations
 
@@ -108,13 +109,16 @@ _batch_ids = itertools.count(1)
 
 
 class Batch:
-    """The batch a thread is working on: its id and the host bytes handed
-    to the device for it so far."""
-    __slots__ = ("seq", "h2d_bytes")
+    """The batch a thread is working on: its id, the host bytes handed to
+    the device for it so far, and the rows its exact fp32 gather groups
+    took on the device and on the host."""
+    __slots__ = ("seq", "h2d_bytes", "gather_rows_device", "gather_rows_host")
 
     def __init__(self, seq: int):
         self.seq = seq
         self.h2d_bytes = 0
+        self.gather_rows_device = 0
+        self.gather_rows_host = 0
 
 
 def new_batch() -> int:
@@ -155,6 +159,18 @@ def count_h2d(nbytes: int) -> None:
     b = _local.batch
     if b is not None:
         b.h2d_bytes += int(nbytes)
+
+
+def count_gather_rows(n: int, on_device: bool) -> None:
+    """Add ``n`` candidate rows of an exact fp32 gather group, taken by id
+    on the device or copied on the host, to the thread's batch."""
+    b = _local.batch
+    if b is None:
+        return
+    if on_device:
+        b.gather_rows_device += int(n)
+    else:
+        b.gather_rows_host += int(n)
 
 
 class span:

@@ -415,9 +415,12 @@ class DirectoryVectorDB:
             fetch0 = self.store.rescore_fetch_bytes
             retries0 = self.store.host_fetch_retries
             h2d0 = cur.h2d_bytes
+            dev0, host0 = cur.gather_rows_device, cur.gather_rows_host
             launch(groups, out_scores, out_ids, acct)
             acct.ann_ns = time.perf_counter_ns() - plan.end_ns
             acct.h2d_bytes = cur.h2d_bytes - h2d0
+            acct.gather_rows_device = cur.gather_rows_device - dev0
+            acct.gather_rows_host = cur.gather_rows_host - host0
         # resident-store byte terms are *alive-row* bytes: tombstoned rows
         # still occupy buffer slots but are not part of the serving corpus
         if any(g.precision == "int8" for g in groups):

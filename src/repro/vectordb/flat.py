@@ -318,11 +318,29 @@ def _gather_topk(queries: jnp.ndarray, cand_rows: jnp.ndarray,
     return jax.lax.top_k(jnp.where(valid[None, :], scores, -jnp.inf), k)
 
 
+@functools.partial(jax.jit, static_argnames=("k", "metric"))
+def _gather_topk_ids(queries: jnp.ndarray, rows: jnp.ndarray,
+                     ids: jnp.ndarray, m: jnp.ndarray,
+                     k: int, metric: str) -> Tuple[jnp.ndarray, jnp.ndarray]:
+    """:func:`_gather_topk` over rows taken by id from the device-resident
+    store (``ids`` int32, padded past ``m`` with an in-bounds id whose lanes
+    are masked). Only the ids are padded: padding ``rows`` would copy the
+    whole store."""
+    cand_rows = jnp.take(rows, ids, axis=0, mode="clip")
+    valid = jnp.arange(ids.shape[0]) < m
+    return _gather_topk(queries, cand_rows, valid, k=k, metric=metric)
+
+
 class FlatExecutor:
     name = "flat"
 
-    def __init__(self, store: VectorStore):
+    def __init__(self, store: VectorStore, device_gather: bool = True):
         self.store = store
+        # the gather plan takes rows by id from the store's device copy
+        # unless the rows are not on one device: a tiered store keeps them
+        # in host RAM, and the sharded tier's twin (``device_gather=False``)
+        # serves a store that need not fit one device
+        self.device_gather = device_gather
 
     def _sq(self) -> jnp.ndarray:
         """Cached device squared norms for the l2 scan — an empty array for
@@ -374,12 +392,28 @@ class FlatExecutor:
         qp = pad_rows(queries, bucket(b))
         if plan == "gather":
             mp = bucket(m)
-            with tracing.span("dsq.gather.rows"):
-                rows = pad_rows(self.store.vectors[candidate_ids], mp)
-            qp, rows, valid = to_device(qp, rows, np.arange(mp) < m)
-            with tracing.span("dsq.launch"):
-                scores, local = _gather_topk(qp, rows, valid, kk,
-                                             self.store.metric)
+            on_device = self.device_gather and not self.store.tiered_active()
+            tracing.count_gather_rows(m, on_device)
+            if on_device:
+                with tracing.span("dsq.gather.rows"):
+                    # the device take clamps; an id past the store must
+                    # fail as the host fancy-index does
+                    if int(np.max(candidate_ids)) >= n:
+                        raise IndexError("candidate id past the store")
+                    ids = np.zeros(mp, np.int32)
+                    ids[:m] = candidate_ids
+                qp, ids, m_d = to_device(qp, ids, np.int32(m))
+                with tracing.span("dsq.launch"):
+                    scores, local = _gather_topk_ids(
+                        qp, self.store.device_vectors(), ids, m_d, kk,
+                        self.store.metric)
+            else:
+                with tracing.span("dsq.gather.rows"):
+                    rows = pad_rows(self.store.vectors[candidate_ids], mp)
+                qp, rows, valid = to_device(qp, rows, np.arange(mp) < m)
+                with tracing.span("dsq.launch"):
+                    scores, local = _gather_topk(qp, rows, valid, kk,
+                                                 self.store.metric)
             scores, local = to_host(scores, local)
             ids = candidate_ids[local[:b]]
         else:

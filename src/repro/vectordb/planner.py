@@ -305,6 +305,10 @@ class BatchAccounting:
     directory_ns: int = 0            # total resolve+plan time, whole batch
     ann_ns: int = 0                  # total ranking time, whole batch
     h2d_bytes: int = 0               # host bytes handed to the device
+    # exact fp32 gather-plan rows taken by id from the device-resident
+    # store, and rows copied on the host and uploaded (tiered, sharded)
+    gather_rows_device: int = 0
+    gather_rows_host: int = 0
     resolve_stats: ResolveStats = field(default_factory=ResolveStats)
     # sharded-executor terms (zero on single-device paths): what this batch
     # actually moved between host and mesh, and across the mesh

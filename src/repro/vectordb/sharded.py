@@ -81,7 +81,9 @@ class ShardedExecutor:
         self.store = store
         self.mesh = mesh
         self.view = ShardedStoreView(store, mesh)
-        self.flat = FlatExecutor(store)      # gather-plan twin
+        # gather-plan twin; it gathers rows on the host, so a gather-only
+        # batch never puts the whole store on one device
+        self.flat = FlatExecutor(store, device_gather=False)
         self.table_slots = table_slots
         self._slots: "OrderedDict[Tuple[str, object], _Slot]" = OrderedDict()
         self._free: List[int] = []

@@ -181,9 +181,10 @@ def test_served_batch_spans_tile_and_share_the_accounting_seq(ring, db):
                                      if s.name == "dsq.plan")
     stage = next(s.ns for s in spans if s.name == "sched.stage")
     assert abs(acct.sched_stage_ns - stage) <= 1     # via float seconds
-    # h2d: the gather group (2 queries, 4 rows, their mask) and the scan
-    # (1 query, the packed words of 400 rows, the scope ids)
-    gather = (bucket(2) * DIM * 4 + bucket(4) * DIM * 4 + bucket(4))
+    # h2d: the gather group (2 queries, its 4 row ids padded, their count:
+    # the rows are taken on the device) and the scan (1 query, the packed
+    # words of 400 rows, the scope ids)
+    gather = bucket(2) * DIM * 4 + bucket(4) * 4 + 4
     scan = bucket(1) * DIM * 4 + bucket(1) * ((400 + 31) // 32) * 4 \
         + bucket(1) * 4
     assert acct.h2d_bytes == gather + scan
